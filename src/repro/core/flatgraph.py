@@ -36,6 +36,7 @@ remains as the oracle (``Matcher(g, use_flat=False)``).
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -69,12 +70,11 @@ _NO_PROPS: Dict[str, str] = {}
 # ---------------------------------------------------------------------- #
 # vectorized per-level aggregate sweep (numpy / jax.jit dispatch)
 # ---------------------------------------------------------------------- #
-def _jax_backend() -> str:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:       # pragma: no cover - jax-less install
-        return ""
+def _on_accelerator() -> bool:
+    """True when JAX's default backend is not the CPU: the platform
+    gate of every ``use_jax='auto'`` dispatch in this module."""
+    import jax
+    return jax.default_backend() != "cpu"
 
 
 def aggregate_sweep(own: np.ndarray, parent: np.ndarray,
@@ -89,8 +89,7 @@ def aggregate_sweep(own: np.ndarray, parent: np.ndarray,
     ``use_jax='auto'`` follows the ``kernels/ops.py`` idiom: the jitted
     scan runs on accelerator backends, numpy everywhere else.
     """
-    if use_jax == "numpy" or (use_jax == "auto"
-                              and _jax_backend() in ("", "cpu")):
+    if use_jax == "numpy" or (use_jax == "auto" and not _on_accelerator()):
         agg = own.copy()
         for lvl in reversed(levels[1:]):        # deepest first
             par = parent[lvl]
@@ -99,12 +98,13 @@ def aggregate_sweep(own: np.ndarray, parent: np.ndarray,
     return _aggregate_sweep_jax(own, parent, levels)
 
 
-def _aggregate_sweep_jax(own: np.ndarray, parent: np.ndarray,
-                         levels: Sequence[np.ndarray]) -> np.ndarray:
-    """jax.jit per-level scan: each level is one ``.at[].add`` scatter
-    (XLA segment-sum); retraced per topology, cached across calls."""
+@functools.lru_cache(maxsize=None)
+def _sweep_fn():
+    """The one jitted per-level scan (built on first use, so importing
+    this module never imports jax).  Each level is one ``.at[].add``
+    scatter (XLA segment-sum); jax's cache keys it on the level shapes,
+    so a fixed topology compiles once."""
     import jax
-    import jax.numpy as jnp
 
     @jax.jit
     def sweep(own_j, parent_j, *level_arrays):
@@ -112,9 +112,14 @@ def _aggregate_sweep_jax(own: np.ndarray, parent: np.ndarray,
         for lvl in reversed(level_arrays[1:]):
             agg = agg.at[parent_j[lvl]].add(agg[lvl])
         return agg
+    return sweep
 
-    out = sweep(jnp.asarray(own), jnp.asarray(parent),
-                *[jnp.asarray(l) for l in levels])
+
+def _aggregate_sweep_jax(own: np.ndarray, parent: np.ndarray,
+                         levels: Sequence[np.ndarray]) -> np.ndarray:
+    import jax.numpy as jnp
+    out = _sweep_fn()(jnp.asarray(own), jnp.asarray(parent),
+                      *[jnp.asarray(l) for l in levels])
     return np.asarray(out)
 
 
@@ -415,12 +420,19 @@ class FlatGraph:
                 by[depth[i]].append(i)
             self._levels = [np.asarray(l, np.int64) for l in by]
 
-    def _sweep(self, use_jax: str = "auto") -> None:
-        self.n_agg_sweeps += 1
+    def own_counts(self) -> np.ndarray:
+        """``[n, T]`` one-hot type of every live free vertex: what the
+        aggregate sweep sums up the tree."""
         n, T = self.n, len(self.types)
         own = np.zeros((n, T), np.int32)
         live = np.nonzero(self.present[:n] & self.free[:n])[0]
         own[live, self.type_id[live]] = 1
+        return own
+
+    def _sweep(self, use_jax: str = "auto") -> None:
+        self.n_agg_sweeps += 1
+        n, T = self.n, len(self.types)
+        own = self.own_counts()
         if self._levels:
             agg = aggregate_sweep(own, self.parent[:n], self._levels,
                                   use_jax=use_jax)
@@ -526,7 +538,7 @@ class FlatGraph:
             for t, k in s[3]:
                 need[u, t] = k
         if use_jax == "numpy" or (use_jax == "auto"
-                                  and _jax_backend() in ("", "cpu")):
+                                  and not _on_accelerator()):
             m = batched_candidate_mask(
                 self.type_id[:n], self.free[:n], self.present[:n],
                 self.size[:n], self.prop_mask[:n], self.agg[:n, :T],

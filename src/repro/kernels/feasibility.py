@@ -17,8 +17,9 @@ Layout notes (TPU tiling wants the lane dim = 128):
   have no practical int64 lane support (and jax defaults to x32).
 
 Grid is (N/BN, V/BV), both parallel; callers pad N, V, and T and slice
-the result.  On CPU the kernel runs in interpret mode (tests); the
-jitted XLA reference below is the ``auto`` path off-TPU.
+the result.  ``auto`` runs the compiled kernel on TPU and the jitted XLA
+reference below everywhere else; interpret mode runs only when asked
+for (the CPU parity tests).
 """
 from __future__ import annotations
 
@@ -29,8 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-from .pallas_compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 _BN, _BV = 8, 128           # request x vertex block (8x128 VREG tile)
 _LO31 = (1 << 31) - 1
@@ -110,7 +110,7 @@ def _feasible_pallas(tid, msize, rmlo, rmhi, need,
                   vspec, vspec, vspec, vspec, vspec, aspec],
         out_specs=pl.BlockSpec((_BN, _BV), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_p, v_p), jnp.int32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(tid, msize, rmlo, rmhi, need,
@@ -128,7 +128,11 @@ def batched_feasible_op(vtype: np.ndarray, vok: np.ndarray,
                         use_pallas: str = "auto") -> np.ndarray:
     """[N, V] int32 mask: 1 where request ``i`` can root at vertex
     ``v``.  ``vmask``/``rmask`` are the int64 property bitmasks;
-    ``agg`` is [V, T]; ``need`` is [N, T]."""
+    ``agg`` is [V, T]; ``need`` is [N, T].
+
+    ``use_pallas``: ``'auto'`` (compiled kernel on TPU, XLA elsewhere),
+    ``'pallas'`` (compiled kernel; fails off TPU), ``'interpret'``,
+    ``'xla'``."""
     vmlo, vmhi = _split_mask(vmask)
     rmlo, rmhi = _split_mask(rmask)
     vtype = np.asarray(vtype, np.int32)
@@ -143,7 +147,7 @@ def batched_feasible_op(vtype: np.ndarray, vok: np.ndarray,
         return np.asarray(_ref_batched_feasible(
             vtype, vok, vsize, vmlo, vmhi, agg,
             tid, msize, rmlo, rmhi, need))
-    interpret = use_pallas == "interpret" or _backend() != "tpu"
+    interpret = use_pallas == "interpret"
     n, v = tid.shape[0], vtype.shape[0]
     # pad request rows, vertex lanes, and the type sublane; padded
     # vertices carry vok=0 (never feasible) and padded types need=0

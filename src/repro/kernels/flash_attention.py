@@ -22,8 +22,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from .pallas_compat import CompilerParams as _CompilerParams
-
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -124,7 +122,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((block_q, 1), jnp.float32),   # l
             pltpu.VMEM((block_q, d), jnp.float32),   # acc
         ],
-        compiler_params=_CompilerParams(dimension_semantics=(
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -215,7 +213,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(dimension_semantics=(
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, lengths)

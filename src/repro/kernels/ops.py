@@ -1,8 +1,9 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-``use_pallas='auto'`` selects the Pallas kernel on TPU backends and the
-XLA reference path elsewhere; ``'interpret'`` forces the kernel body to
-run in interpret mode (CPU validation); ``'xla'`` forces the oracle.
+``use_pallas='auto'`` selects the compiled Pallas kernel on TPU backends
+and the XLA reference path elsewhere; ``'pallas'`` forces the compiled
+kernel (it fails off TPU); ``'interpret'`` runs the kernel body in
+interpret mode (CPU validation); ``'xla'`` forces the oracle.
 """
 from __future__ import annotations
 
@@ -26,7 +27,7 @@ def attention_op(q: jax.Array, k: jax.Array, v: jax.Array,
     """q: [b, h, sq, d]; k, v: [b, kvh, skv, d]."""
     if use_pallas == "xla" or (use_pallas == "auto" and _backend() != "tpu"):
         return ref_attention(q, k, v, causal=causal, window=window)
-    interpret = use_pallas == "interpret" or _backend() != "tpu"
+    interpret = use_pallas == "interpret"
     return flash_attention(q, k, v, causal=causal, window=window,
                            interpret=interpret)
 
@@ -43,7 +44,7 @@ def ssd_scan_op(x: jax.Array, dt: jax.Array, A: jax.Array,
         return ssd_chunked(x, dt, A, B, C, chunk,
                            initial_state=initial_state,
                            return_state=return_state)
-    interpret = use_pallas == "interpret" or _backend() != "tpu"
+    interpret = use_pallas == "interpret"
     b, s, H, P = x.shape
     G, N = B.shape[2], B.shape[3]
     nc = s // chunk

@@ -23,8 +23,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .pallas_compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
@@ -104,7 +103,7 @@ def ssd_chunk_pallas(x: jax.Array, dt: jax.Array, A: jax.Array,
             jax.ShapeDtypeStruct((b, nc, H, N, P), jnp.float32),
             jax.ShapeDtypeStruct((b, nc, H), jnp.float32),
         ],
-        compiler_params=_CompilerParams(dimension_semantics=(
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel")),
         interpret=interpret,
     )(x.reshape(b, nc * chunk, H, P),

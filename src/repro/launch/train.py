@@ -26,6 +26,7 @@ from ..optim.adamw import OptConfig
 from ..runtime.checkpoint import CheckpointManager
 from ..runtime.elastic import ElasticRuntime
 from ..runtime.fault import FaultPolicy, HeartbeatMonitor
+from .compile_cache import enable_compile_cache
 
 
 def run_training(arch: str, steps: int = 20, smoke: bool = True,
@@ -120,6 +121,7 @@ def main() -> None:
     ap.add_argument("--perf", action="store_true",
                     help="apply the per-arch §Perf optimization bundle")
     args = ap.parse_args()
+    enable_compile_cache()
     run_training(args.arch, steps=args.steps, smoke=args.smoke,
                  grow_at=args.grow_at, shrink_at=args.shrink_at,
                  fail_at=args.fail_at, ckpt_dir=args.ckpt_dir,

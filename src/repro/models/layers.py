@@ -5,9 +5,9 @@ sharding constraints are expressed with logical axis names (see
 ``repro.parallel.sharding``); with ``mesh=None`` they are no-ops and the
 same code runs in CPU smoke tests.
 
-Attention uses the XLA einsum path by default (the Pallas flash kernel
-in ``repro.kernels`` is validated separately in interpret mode and can be
-enabled with ``use_pallas=True`` on real TPU runtimes).
+Attention uses the XLA einsum path.  The Pallas kernels in
+``repro.kernels`` (flash attention, SSD scan) are not on the model path;
+they are validated separately in interpret mode.
 """
 from __future__ import annotations
 
@@ -32,8 +32,11 @@ class ParamSpec:
     init: str = "normal"                 # normal | zeros | ones | small
     dtype: str = "float32"
 
-    def materialize(self, key: jax.Array) -> jax.Array:
-        dt = jnp.dtype(self.dtype)
+    def materialize(self, key: jax.Array,
+                    dtype: Optional[str] = None) -> jax.Array:
+        """Draw the parameter directly in ``dtype`` (default: the
+        spec's own), so no wider copy of it is ever built."""
+        dt = jnp.dtype(dtype or self.dtype)
         if self.init == "zeros":
             return jnp.zeros(self.shape, dt)
         if self.init == "ones":
@@ -42,14 +45,14 @@ class ParamSpec:
         scale = 1.0 / np.sqrt(max(fan_in, 1))
         if self.init == "small":
             scale *= 0.1
-        return (jax.random.normal(key, self.shape, jnp.float32) * scale).astype(dt)
+        return jax.random.normal(key, self.shape, dt) * jnp.asarray(scale, dt)
 
 
-def materialize_tree(specs, key: jax.Array):
+def materialize_tree(specs, key: jax.Array, dtype: Optional[str] = None):
     leaves, treedef = jax.tree_util.tree_flatten(
         specs, is_leaf=lambda x: isinstance(x, ParamSpec))
     keys = jax.random.split(key, len(leaves))
-    vals = [l.materialize(k) for l, k in zip(leaves, keys)]
+    vals = [l.materialize(k, dtype) for l, k in zip(leaves, keys)]
     return jax.tree_util.tree_unflatten(treedef, vals)
 
 

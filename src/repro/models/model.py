@@ -33,8 +33,10 @@ class Model:
     def param_specs(self):
         return init_specs(self.cfg)
 
-    def init_params(self, key: jax.Array):
-        return materialize_tree(self.param_specs(), key)
+    def init_params(self, key: jax.Array, dtype: Optional[str] = None):
+        """Random parameters; ``dtype`` overrides the specs' float32
+        (serving draws its weights directly in ``cfg.dtype``)."""
+        return materialize_tree(self.param_specs(), key, dtype)
 
     def param_shardings(self):
         return tree_shardings(self.param_specs(), self.ctx)

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 
 
 from ..parallel.sharding import (ShardingCtx, constrain,
-                                shard_map_compat as _shard_map)
+                                shard_map_unchecked as _shard_map)
 from .config import ArchConfig
 from .layers import ParamSpec, rmsnorm
 

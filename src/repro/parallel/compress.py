@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .sharding import shard_map_compat as _shard_map
+from .sharding import shard_map_unchecked as _shard_map
 
 
 def quantize_int8(x: jax.Array, key: jax.Array) -> Tuple[jax.Array, jax.Array]:

@@ -28,18 +28,11 @@ from typing import Dict, Optional, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax >= 0.5 exposes jax.shard_map(check_vma=...); jax 0.4.x has
-# jax.experimental.shard_map.shard_map(check_rep=...).  Accept either.
-if hasattr(jax, "shard_map"):
-    def shard_map_compat(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_04
+def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` without the replication (vma) check."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-    def shard_map_compat(f, *, mesh, in_specs, out_specs):
-        return _shard_map_04(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
 
 Physical = Union[None, str, Tuple[str, ...]]
 
