@@ -159,8 +159,9 @@ class GrowEngine:
     ``external_at_any_level``, ``allocations``, ``timings``,
     ``external_paths``, ``spliced_paths``, ``lock`` (an RLock guarding
     local mutations — the engine acquires it per stage, never across a
-    transport call), and optionally ``eventlog`` (typed GROW/REVOKE
-    events).  ``SchedulerInstance`` is the only host today; the
+    transport call), the ``n_matches``/``n_match_hits`` counters, and
+    optionally ``eventlog`` (typed GROW/REVOKE events) and
+    ``span_collector``.  ``SchedulerInstance`` is the only host today; the
     indirection is what lets the caller and RPC-server sides share one
     implementation.
     """
@@ -197,19 +198,17 @@ class GrowEngine:
                               encode=encode, priority=priority,
                               preempt=preempt, stages=None)
         stages: Dict[str, float] = {}
-        t0 = time.perf_counter()
-        res = self._grow(jobspec, jobid, requester=requester,
-                         encode=encode, priority=priority,
-                         preempt=preempt, stages=stages)
-        dur = time.perf_counter() - t0
-        rec = res.timing
-        if rec is not None:
-            stages["local_match"] = rec.t_match
-            if rec.t_add_upd:
-                stages["splice"] = rec.t_add_upd
-        col.record({"name": "match_grow", "level": self.host.name,
-                    "jobid": jobid, "ok": bool(res), "via": res.via,
-                    "dur": dur, "stages": stages})
+        with col.span("match_grow", level=self.host.name, jobid=jobid,
+                      stages=stages) as sp:
+            res = self._grow(jobspec, jobid, requester=requester,
+                             encode=encode, priority=priority,
+                             preempt=preempt, stages=stages)
+            rec = res.timing
+            if rec is not None:
+                stages["local_match"] = rec.t_match
+                if rec.t_add_upd:
+                    stages["splice"] = rec.t_add_upd
+            sp.attrs.update(ok=bool(res), via=res.via)
         return res
 
     def _grow(self, jobspec: Jobspec, jobid: str, *,
@@ -229,7 +228,9 @@ class GrowEngine:
             matcher = Matcher(host.graph)
             paths = matcher.match(jobspec)
             rec.t_match = time.perf_counter() - t0
+            host.n_matches += 1
             if paths is not None:
+                host.n_match_hits += 1
                 host.graph.set_allocated(paths, jobid)
                 self._book(jobid, paths)
                 if encode:

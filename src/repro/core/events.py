@@ -127,6 +127,10 @@ class EventLog:
         # one callback (and one frame) per event
         self._sinks: List[Tuple[Callable[[List[JobEvent]], None],
                                 int]] = []
+        # trace-span sink (core/metrics.SpanCollector), set through the
+        # owning SchedulerInstance's ``span_collector``: each delivery
+        # chunk is an ``events.subscribers`` span (subscribers' code)
+        self.span_collector = None
 
     # ------------------------------------------------------------------ #
     def emit(self, type: EventType, jobid: str,
@@ -190,22 +194,12 @@ class EventLog:
                          for _ in range(min(len(self._delivery), 256))]
                 subs = list(self._subscribers)
                 sinks = list(self._sinks)
-            for ev in chunk:
-                for cb, joined in subs:
-                    if ev.seq < joined:
-                        continue    # predates this subscriber
-                    try:
-                        cb(ev)
-                    except Exception:
-                        pass
-            for scb, joined in sinks:
-                batch = [e for e in chunk if e.seq >= joined]
-                if not batch:
-                    continue
-                try:
-                    scb(batch)
-                except Exception:
-                    pass
+            col = self.span_collector
+            if col is None:
+                _deliver(chunk, subs, sinks)
+            else:
+                with col.span("events.subscribers", n=len(chunk)):
+                    _deliver(chunk, subs, sinks)
 
     # ------------------------------------------------------------------ #
     def since(self, cursor: int = 0) -> Tuple[List[JobEvent], int]:
@@ -292,3 +286,28 @@ class EventLog:
         """The cursor pointing just past the newest event."""
         with self._lock:
             return self._next
+
+
+def _deliver(chunk: List[JobEvent],
+             subs: List[Tuple[Callable[[JobEvent], None], int]],
+             sinks: List[Tuple[Callable[[List[JobEvent]], None], int]]
+             ) -> None:
+    """Hand one chunk of events to the live subscribers and batch sinks
+    that had joined before each event; a callback that raises is
+    skipped so it cannot abort the emitting operation."""
+    for ev in chunk:
+        for cb, joined in subs:
+            if ev.seq < joined:
+                continue        # predates this subscriber
+            try:
+                cb(ev)
+            except Exception:
+                pass
+    for scb, joined in sinks:
+        batch = [e for e in chunk if e.seq >= joined]
+        if not batch:
+            continue
+        try:
+            scb(batch)
+        except Exception:
+            pass

@@ -43,6 +43,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .jobspec import Jobspec, ResourceReq
+from .metrics import NO_SPAN
 
 # vertices below this count: vectorized prefilters cost more than the
 # plain int-DFS saves, so FlatMatcher skips them (the arrays are still
@@ -185,6 +186,14 @@ class FlatGraph:
         self.n_agg_sweeps = 0       # vectorized struct-change sweeps
         self.n_bubbles = 0          # incremental dirty-propagations
         self.n_sync_fast = 0        # sync() calls short-circuited clean
+        # batched scans (feasible_roots_batch): calls, request rows,
+        # rows after signature dedupe, and on the device path the bytes
+        # copied to the device and pulled back
+        self.n_scans = 0
+        self.n_scan_rows = 0
+        self.n_scan_unique = 0
+        self.scan_h2d_bytes = 0
+        self.scan_d2h_bytes = 0
         self._build()
 
     # -- construction --------------------------------------------------- #
@@ -386,10 +395,19 @@ class FlatGraph:
 
         Fast path: the mutation hooks stamp ``_synced_version`` stale,
         so a second sync in the same kick (dispatcher, then matcher,
-        then a feasibility scan) is a single int compare."""
+        then a feasibility scan) is a single int compare.  A sync that
+        does work is a ``flat.sync`` span."""
         if self.g.version == self._synced_version:
             self.n_sync_fast += 1
             return
+        col = self.g.span_collector
+        if col is None:
+            self._settle(use_jax)
+            return
+        with col.span("flat.sync", struct=self._struct_dirty):
+            self._settle(use_jax)
+
+    def _settle(self, use_jax: str) -> None:
         if self._struct_dirty:
             self._refresh_levels()
             self._sweep(use_jax)
@@ -434,8 +452,10 @@ class FlatGraph:
         n, T = self.n, len(self.types)
         own = self.own_counts()
         if self._levels:
-            agg = aggregate_sweep(own, self.parent[:n], self._levels,
-                                  use_jax=use_jax)
+            col = self.g.span_collector
+            with NO_SPAN if col is None else col.span("flat.sweep"):
+                agg = aggregate_sweep(own, self.parent[:n], self._levels,
+                                      use_jax=use_jax)
         else:
             agg = own
         self.agg[:n, :T] = agg
@@ -513,42 +533,67 @@ class FlatGraph:
 
         Dispatch follows :func:`aggregate_sweep`: numpy on CPU
         backends, the ``kernels/feasibility.py`` jax/Pallas variant on
-        accelerators (``use_jax='jax'`` forces it)."""
+        accelerators (``use_jax='jax'`` forces it).
+
+        With a span collector on the graph, a call is a ``flat.scan``
+        span holding ``scan.prep`` (signature dedupe, the request
+        matrix, and on the device path the casts and pads) and, on the
+        device path, ``scan.device`` (dispatch to the mask back on the
+        host)."""
+        col = self.g.span_collector
+        if col is None:
+            return self._scan(reqs, use_jax, None)
+        with col.span("flat.scan", rows=len(reqs)):
+            return self._scan(reqs, use_jax, col)
+
+    def _scan(self, reqs: Sequence[ResourceReq], use_jax: str,
+              col) -> np.ndarray:
         self.sync(use_jax)
         n, N = self.n, len(reqs)
+        self.n_scans += 1
+        self.n_scan_rows += N
         out = np.zeros((N, n), bool)
         if N == 0 or n == 0:
             return out
-        sig_rows: Dict[Tuple, List[int]] = {}
-        for i, req in enumerate(reqs):
-            c = self.compiled(req)
-            if c.tid is None:       # some required type absent: no row
-                continue
-            sig = (c.tid, c.min_size, c.req_mask, tuple(c.agg_need))
-            sig_rows.setdefault(sig, []).append(i)
-        if not sig_rows:
-            return out
-        uniq = list(sig_rows)
-        U, T = len(uniq), len(self.types)
-        tid = np.fromiter((s[0] for s in uniq), np.int32, U)
-        min_size = np.fromiter((s[1] for s in uniq), np.int32, U)
-        req_mask = np.fromiter((s[2] for s in uniq), np.int64, U)
-        need = np.zeros((U, T), np.int32)
-        for u, s in enumerate(uniq):
-            for t, k in s[3]:
-                need[u, t] = k
-        if use_jax == "numpy" or (use_jax == "auto"
-                                  and not _on_accelerator()):
+        with NO_SPAN if col is None else col.span("scan.prep"):
+            sig_rows: Dict[Tuple, List[int]] = {}
+            for i, req in enumerate(reqs):
+                c = self.compiled(req)
+                if c.tid is None:   # some required type absent: no row
+                    continue
+                sig = (c.tid, c.min_size, c.req_mask, tuple(c.agg_need))
+                sig_rows.setdefault(sig, []).append(i)
+            if not sig_rows:
+                return out
+            uniq = list(sig_rows)
+            U, T = len(uniq), len(self.types)
+            tid = np.fromiter((s[0] for s in uniq), np.int32, U)
+            min_size = np.fromiter((s[1] for s in uniq), np.int32, U)
+            req_mask = np.fromiter((s[2] for s in uniq), np.int64, U)
+            need = np.zeros((U, T), np.int32)
+            for u, s in enumerate(uniq):
+                for t, k in s[3]:
+                    need[u, t] = k
+            on_device = use_jax != "numpy" and (use_jax != "auto"
+                                                or _on_accelerator())
+            if on_device:
+                from ..kernels.feasibility import pack_inputs, run_packed
+                path, args = pack_inputs(
+                    self.type_id[:n], (self.free[:n] & self.present[:n]),
+                    self.size[:n], self.prop_mask[:n], self.agg[:n, :T],
+                    tid, min_size, req_mask, need)
+        self.n_scan_unique += U
+        if on_device:
+            with NO_SPAN if col is None else col.span("scan.device"):
+                full = run_packed(path, args)
+            self.scan_h2d_bytes += sum(a.nbytes for a in args)
+            self.scan_d2h_bytes += full.nbytes
+            m = full[:U, :n] != 0
+        else:
             m = batched_candidate_mask(
                 self.type_id[:n], self.free[:n], self.present[:n],
                 self.size[:n], self.prop_mask[:n], self.agg[:n, :T],
                 tid, min_size, req_mask, need)
-        else:
-            from ..kernels.feasibility import batched_feasible_op
-            m = batched_feasible_op(
-                self.type_id[:n], (self.free[:n] & self.present[:n]),
-                self.size[:n], self.prop_mask[:n], self.agg[:n, :T],
-                tid, min_size, req_mask, need) != 0
         for u, s in enumerate(uniq):
             row = m[u]
             for i in sig_rows[s]:
@@ -688,8 +733,10 @@ class FlatMatcher:
                               f.size[:n], f.prop_mask[:n], f.agg[:n],
                               c.tid, c.min_size, c.req_mask, c.agg_need)
         own = mask.astype(np.int32)[:, None]
-        agg = aggregate_sweep(own, f.parent[:n], f._levels,
-                              use_jax=self.use_jax)
+        col = f.g.span_collector
+        with NO_SPAN if col is None else col.span("flat.sweep"):
+            agg = aggregate_sweep(own, f.parent[:n], f._levels,
+                                  use_jax=self.use_jax)
         return agg[:, 0].tolist()
 
     def _agg(self, tid: int) -> List[int]:

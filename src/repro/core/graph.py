@@ -141,6 +141,10 @@ class ResourceGraph:
         # tests assert this stays frozen across alloc/release/splice/
         # revoke (rebuilds are a build-time-only cost).
         self.n_agg_rebuilds = 0
+        # trace-span sink (core/metrics.SpanCollector), set through the
+        # owning SchedulerInstance's ``span_collector``: the matcher and
+        # the flat mirror read it here.  None costs them one check.
+        self.span_collector = None
 
     def flat(self):
         """The flat-array mirror of this graph (built on first use,

@@ -45,7 +45,18 @@ class Matcher:
 
         Matching is exclusive: a matched vertex must be free, and all
         vertices named by the (nested) request under it are claimed.
+        With a span collector on the graph, each match is a ``match``
+        span.
         """
+        col = self.g.span_collector
+        if col is None:
+            return self._match(jobspec)
+        with col.span("match") as sp:
+            got = self._match(jobspec)
+            sp.attrs["ok"] = got is not None
+            return got
+
+    def _match(self, jobspec: Jobspec) -> Optional[List[str]]:
         use_flat = self.use_flat
         if use_flat and self._auto:
             # auto dispatch also weighs the request: a small request on

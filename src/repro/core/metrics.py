@@ -29,11 +29,19 @@ journal — instead of polling internals:
   replay==live equivalence exact.
 
 * :class:`SpanCollector` — a bounded pull-drained buffer for the
-  structured trace spans ``GrowEngine`` (and ``SchedulerInstance``
-  release) record per stage: local match → reclaim → revoke → forward
-  → external → splice.  Producers pay one ``is None`` check when no
-  collector is attached; ``record`` takes only the collector's own
-  lock and never calls out (the R2/R3 concurrency contract).
+  host spans of the scheduling plane: the queue's passes, submits and
+  job ends, the policy and its reservation ledger, the matcher, the
+  flat mirror's syncs, sweeps and batched scans, event delivery, and
+  ``GrowEngine``'s per-stage ``match_grow`` spans.  Each record holds
+  its start on ``time.perf_counter()``, its duration, an id and the id
+  of the enclosing span on the same thread.  While a profiler trace
+  runs, every attached span is also a ``jax.profiler.TraceAnnotation``
+  named ``repro.<name>``, which puts it on the device ops' clock.
+  Producers reach the collector through the objects they hold (the
+  scheduler's ``span_collector``, kept on its graph and event log);
+  with none attached a span costs one ``is None`` check.  ``record``
+  takes only the collector's own lock and never calls out (the R2/R3
+  concurrency contract).
 
 * :func:`fragmentation` — largest-free-block vs total-free per type,
   computed from the same per-vertex pruning aggregates the
@@ -46,15 +54,19 @@ is how the dashboard consumer (``runtime/dashboard.py``) builds the
 from __future__ import annotations
 
 import collections
+import contextlib
+import functools
+import itertools
 import math
 import threading
+import time
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 from ..analysis.lockwitness import named_lock
 from .events import EventType, JobEvent
 
 __all__ = ["QuantileSketch", "SpanCollector", "MetricsAggregator",
-           "fragmentation"]
+           "NO_SPAN", "fragmentation"]
 
 
 # ---------------------------------------------------------------------- #
@@ -142,21 +154,88 @@ class QuantileSketch:
 # ---------------------------------------------------------------------- #
 # trace spans
 # ---------------------------------------------------------------------- #
+#: the shared no-op context of a detached span site:
+#: ``with NO_SPAN if col is None else col.span(name):`` allocates nothing
+NO_SPAN = contextlib.nullcontext()
+
+
+@functools.lru_cache(maxsize=None)
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on the first attached
+    span (so importing the scheduling plane never imports jax)."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
+class _Span:
+    """One open span of a :class:`SpanCollector` (see
+    :meth:`SpanCollector.span`).  ``attrs`` may be filled while it is
+    open; the record is written when it closes."""
+
+    __slots__ = ("col", "name", "attrs", "id", "parent", "t0", "_tid",
+                 "_ann")
+
+    def __init__(self, col: "SpanCollector", name: str, attrs: Dict):
+        self.col = col
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self) -> "_Span":
+        col = self.col
+        open_ = col._open
+        tid = self._tid = threading.get_ident()
+        self.parent = open_.get(tid)
+        self.id = open_[tid] = next(col._ids)
+        ann = _trace_annotation()
+        if ann.is_enabled():        # a profiler trace is running
+            self._ann = ann("repro." + self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        col = self.col
+        col._open[self._tid] = self.parent
+        rec = {"name": self.name, "t0": self.t0, "dur": t1 - self.t0,
+               "id": self.id, "parent": self.parent}
+        if self.attrs:
+            rec.update(self.attrs)
+        col._spans.append(rec)      # what record() does, one call less
+        col.recorded += 1
+
+
 class SpanCollector:
     """Bounded buffer for structured span records (plain dicts).
 
-    Producers (``GrowEngine.grow``, ``SchedulerInstance.release``) call
-    :meth:`record` with ``{"name", "level", "jobid", "ok", "via",
-    "dur", "stages": {stage: seconds}}``; consumers :meth:`drain` on
-    their own schedule.  ``record`` is one atomic deque append — no
-    lock — and never emits, calls back, or touches a transport; the
-    producer may hold a scheduler lock's *caller* frame, so obeying
-    R2/R3 here is load-bearing, not style."""
+    Producers open spans with :meth:`span`; each closed span records
+    ``{"name", "t0", "dur", "id", "parent", **attrs}``: ``t0`` on
+    ``time.perf_counter()``, ``parent`` the id of the span that was
+    open on the same thread (None at the top).  ``match_grow`` and
+    ``release`` spans also carry ``level``, ``jobid``, ``ok``, ``via``
+    and ``stages`` ({stage: seconds}), which :meth:`MetricsAggregator.
+    consume_spans` folds.  Consumers :meth:`drain` on their own
+    schedule.  ``record`` is one atomic deque append — no lock — and
+    never emits, calls back, or touches a transport; the producer may
+    hold a scheduler lock's *caller* frame, so obeying R2/R3 here is
+    load-bearing, not style."""
 
     def __init__(self, maxlen: int = 65536):
         self._lock = named_lock("spancollector")
         self._spans: Deque[Dict] = collections.deque(maxlen=maxlen)
         self.recorded = 0           # monotonic (drain does not reset)
+        self._ids = itertools.count(1)
+        self._open: Dict[int, Optional[int]] = {}   # thread -> open span
+
+    def span(self, name: str, **attrs) -> _Span:
+        """Context manager timing one span named ``name``, nested under
+        the span open on this thread; while a profiler trace runs it is
+        also a trace annotation ``repro.<name>``."""
+        return _Span(self, name, attrs)
 
     def record(self, span: Dict) -> None:
         # lock-free: deque.append is atomic and bounded by maxlen; a

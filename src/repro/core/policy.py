@@ -47,6 +47,7 @@ from typing import Dict, List, Optional, Tuple, TYPE_CHECKING
 import numpy as np
 
 from .flatgraph import FLAT_MIN_VERTICES, flat_enabled
+from .metrics import NO_SPAN
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from .queue import Job, JobQueue
@@ -125,6 +126,15 @@ class EasyBackfill(PriorityFCFS):
         self.ledger = ledger
 
     def backfill(self, queue: "JobQueue", head: "Job") -> int:
+        """Start what may run ahead of the blocked ``head``; a
+        ``policy.backfill`` span when a span collector is attached."""
+        col = queue.scheduler.span_collector
+        if col is None:
+            return self._backfill(queue, head)
+        with col.span("policy.backfill"):
+            return self._backfill(queue, head)
+
+    def _backfill(self, queue: "JobQueue", head: "Job") -> int:
         now = queue.clock.now()
         fast = self.ledger and getattr(queue, "ledger", None) is not None
         if (self.max_candidates is None and fast and _sched_pure(queue)
@@ -172,8 +182,7 @@ class EasyBackfill(PriorityFCFS):
                         job._bf_version, job._bf_head = gv, hseq
                     continue
             if queue.start_if_fits(job):
-                queue._log(f"t={now:.3f} backfill {job.jobid} ahead of "
-                           f"{head.jobid} (shadow={shadow})")
+                queue.n_backfilled += 1
                 started += 1
                 # availability changed: the shadow may have moved
                 shadow = shadow_time(queue, head, use_ledger=fast)
@@ -253,9 +262,7 @@ class EasyBackfill(PriorityFCFS):
                         and self._delays_head(queue, head, job, shadow):
                     continue
                 if queue.start_if_fits(job):
-                    queue._log(f"t={now:.3f} backfill {job.jobid} "
-                               f"ahead of {head.jobid} "
-                               f"(shadow={shadow})")
+                    queue.n_backfilled += 1
                     started += 1
                     shadow = shadow_time(queue, head, use_ledger=True)
                     structural = not _deficit(queue, head)
@@ -320,15 +327,17 @@ class EasyBackfill(PriorityFCFS):
         head_tc = head.jobspec.type_counts()
         led = queue.ledger
         delays = np.empty(S, bool)
-        for s, (spec, _grow, _prio) in enumerate(mir.sig_entries):
-            need = spec.type_counts()
-            dprime = {}
-            for t, nh in head_tc.items():
-                d = nh - (free.get(t, 0) - need.get(t, 0))
-                if d > 0:
-                    dprime[t] = d
-            after = now if not dprime else led.cover_time(dprime)
-            delays[s] = _later(after, shadow)
+        col = g.span_collector
+        with NO_SPAN if col is None else col.span("policy.ledger"):
+            for s, (spec, _grow, _prio) in enumerate(mir.sig_entries):
+                need = spec.type_counts()
+                dprime = {}
+                for t, nh in head_tc.items():
+                    d = nh - (free.get(t, 0) - need.get(t, 0))
+                    if d > 0:
+                        dprime[t] = d
+                after = now if not dprime else led.cover_time(dprime)
+                delays[s] = _later(after, shadow)
         queue._sigv_delays = (key_d, delays)
         return fit, delays
 
@@ -367,7 +376,6 @@ class ConservativeBackfill(PriorityFCFS):
         self.max_candidates = max_candidates
 
     def backfill(self, queue: "JobQueue", head: "Job") -> int:
-        now = queue.clock.now()
         started = 0
         tested = 0
         snapshot = list(queue.pending)
@@ -396,8 +404,7 @@ class ConservativeBackfill(PriorityFCFS):
                    for j in ahead):
                 continue            # would push someone's reservation
             if queue.start_if_fits(job):
-                queue._log(f"t={now:.3f} backfill {job.jobid} "
-                           f"(conservative: no reservation delayed)")
+                queue.n_backfilled += 1
                 started += 1
                 gone.add(id(job))
                 before = None       # availability changed: recompute
@@ -416,7 +423,6 @@ class FirstFit(PriorityFCFS):
         self.max_candidates = max_candidates
 
     def backfill(self, queue: "JobQueue", head: "Job") -> int:
-        now = queue.clock.now()
         started = 0
         tested = 0
         for job in list(queue.pending):
@@ -428,7 +434,7 @@ class FirstFit(PriorityFCFS):
                 continue
             tested += 1
             if queue.start_if_fits(job):
-                queue._log(f"t={now:.3f} backfill {job.jobid} (firstfit)")
+                queue.n_backfilled += 1
                 started += 1
         return started
 
@@ -830,7 +836,17 @@ def shadow_time(queue: "JobQueue", head: "Job",
 
     Default path: binary searches over the reservation ledger's
     prefix-sum curves.  ``use_ledger=False`` is the seed's end-time-
-    order walk over ``queue.running`` (the equivalence oracle)."""
+    order walk over ``queue.running`` (the equivalence oracle).  A
+    ``policy.ledger`` span when a span collector is attached."""
+    col = queue.scheduler.span_collector
+    if col is None:
+        return _shadow_time(queue, head, use_ledger)
+    with col.span("policy.ledger"):
+        return _shadow_time(queue, head, use_ledger)
+
+
+def _shadow_time(queue: "JobQueue", head: "Job",
+                 use_ledger: bool) -> Optional[float]:
     deficit = _deficit(queue, head)
     if not deficit:
         # structurally blocked despite sufficient counts: reserve
@@ -863,7 +879,17 @@ def _ledger_head_reservation(queue: "JobQueue", head: "Job",
     hypothetically running from now for its walltime.  The candidate's
     vertices leave availability immediately (raising the head's
     deficit) and come back as one extra release event at
-    ``now + job.walltime``."""
+    ``now + job.walltime``.  A ``policy.ledger`` span when a span
+    collector is attached."""
+    col = queue.scheduler.span_collector
+    if col is None:
+        return _head_reservation(queue, head, job)
+    with col.span("policy.ledger"):
+        return _head_reservation(queue, head, job)
+
+
+def _head_reservation(queue: "JobQueue", head: "Job",
+                      job: "Job") -> Optional[float]:
     now = queue.clock.now()
     avail = _free_counts(queue)
     need_j = job.jobspec.type_counts()

@@ -68,6 +68,7 @@ from typing import Dict, List, Optional
 from ..analysis.lockwitness import named_rlock
 from .events import EventLog, EventType
 from .jobspec import Jobspec
+from .metrics import NO_SPAN
 from .policy import (EasyBackfill, PriorityFCFS, ReservationLedger,
                      SchedulingPolicy, _path_type_counts, _PendingMirror)
 from .scheduler import SchedulerInstance
@@ -222,8 +223,6 @@ class JobQueue:
         self.pending: List[Job] = []
         self.running: List[Job] = []
         self.completed: List[Job] = []
-        self.events: List[str] = []
-        self.max_events = 10_000        # bounded history for long runs
         # typed event surface (core/events.py): the queue, the engine,
         # and the scheduler all emit into one log per queue, so every
         # consumer observes the same total order
@@ -239,6 +238,8 @@ class JobQueue:
         if scheduler.eventlog is None:
             scheduler.eventlog = self.eventlog
         self.n_preemptions = 0
+        self.n_passes = 0               # step() calls
+        self.n_backfilled = 0           # jobs a policy started past the head
         # incremental reservation ledger (core/policy.py): per-type
         # release timelines of the running jobs, delta-updated by the
         # lifecycle edges below (all under _api_lock) and consumed by
@@ -300,7 +301,7 @@ class JobQueue:
         ``preemptible`` marks the job's allocation as revocable by
         higher-priority work (cross-tenant revokes and preemptive
         policies only ever displace preemptible jobs)."""
-        with self._api_lock:
+        with self._api_lock, self._span("queue.submit"):
             self._accrue()
             seq = next(self._seq)
             jobid = jobid or f"q{seq}-{self.scheduler.name}"
@@ -314,7 +315,6 @@ class JobQueue:
             # key calls per submit a 100k-deep backlog would pay
             bisect.insort(self.pending, job, key=self.policy.sort_key)
             self._pmirror.add(job)
-            self._log(f"t={job.submit_time:.3f} submit {jobid}")
             self.eventlog.emit(EventType.SUBMIT, jobid,
                                alloc_id=job.alloc_id,
                                priority=priority, walltime=walltime)
@@ -378,7 +378,8 @@ class JobQueue:
     def step(self) -> int:
         """Complete due jobs, then schedule from the queue.  Returns the
         number of jobs started."""
-        with self._api_lock:
+        with self._api_lock, self._span("queue.step"):
+            self.n_passes += 1
             self._accrue()
             self._complete_due()
             return self._schedule()
@@ -426,10 +427,11 @@ class JobQueue:
             return list(self.completed)
 
     # -- internals ----------------------------------------------------- #
-    def _log(self, line: str) -> None:
-        self.events.append(line)
-        if len(self.events) > self.max_events:
-            del self.events[:len(self.events) - self.max_events]
+    def _span(self, name: str):
+        """A span of the scheduler's collector (``core/metrics``), or
+        the shared no-op context when none is attached."""
+        col = self.scheduler.span_collector
+        return NO_SPAN if col is None else col.span(name)
 
     def _accrue(self) -> None:
         now = self.clock.now()
@@ -458,9 +460,14 @@ class JobQueue:
         released exactly once."""
         if job not in self.running:
             return
+        with self._span("queue.finish"):
+            self._finish_running(job, state)
+
+    def _finish_running(self, job: Job, state: JobState) -> None:
         self.scheduler.release(job.alloc_id, job.paths)
         self.running.remove(job)
-        self.ledger.job_departed(job.jobid)
+        with self._span("policy.ledger"):
+            self.ledger.job_departed(job.jobid)
         self._preempt_blocked.clear()   # resource state really changed
         job.state = state
         job.end_time = min(job.end_time, self.clock.now()) \
@@ -477,7 +484,6 @@ class JobQueue:
         # priority-0 one behind)
         self._sync_alloc_meta(job.alloc_id)
         self._version += 1
-        self._log(f"t={self.clock.now():.3f} {state.value} {job.jobid}")
         self.eventlog.emit(EventType.FREE, job.jobid, state=state.value,
                            alloc_id=job.alloc_id)
 
@@ -505,9 +511,6 @@ class JobQueue:
                 return False
             job.paths = res.paths()
             job.via = res.via
-            if res.victims:
-                self._log(f"t={self.clock.now():.3f} {job.jobid} "
-                          f"revoked {','.join(res.victims)}")
         else:
             # strictly local MA; several jobs may share one alloc_id,
             # so record only the delta this job contributed
@@ -536,12 +539,11 @@ class JobQueue:
             job.requeue_wait += now - job.preempted_at
             job.preempted_at = None
         self.running.append(job)
-        self.ledger.job_started(job.jobid, job.end_time,
-                                _path_type_counts(self, job))
+        with self._span("policy.ledger"):
+            self.ledger.job_started(job.jobid, job.end_time,
+                                    _path_type_counts(self, job))
         self._sync_alloc_meta(job.alloc_id)
         self._version += 1
-        self._log(f"t={now:.3f} start {job.jobid} via={job.via} "
-                  f"wait={job.wait_time:.3f}")
         self.eventlog.emit(EventType.START, job.jobid, via=job.via,
                            wait=job.wait_time, alloc_id=job.alloc_id)
 
@@ -574,15 +576,10 @@ class JobQueue:
             if not res:
                 return False
             job.paths.extend(res.paths())
-            if res.victims:
-                self._log(f"t={self.clock.now():.3f} {job.jobid} "
-                          f"revoked {','.join(res.victims)}")
             self.ledger.job_resized(job.jobid, job.end_time,
                                     _path_type_counts(self, job))
             self._sync_alloc_meta(job.alloc_id)
             self._version += 1
-            self._log(f"t={self.clock.now():.3f} grow {job.jobid} "
-                      f"+{len(res.new_paths)} via={res.via}")
             # queue-level GROW keyed by the JOB (the engine's GROW is
             # keyed by the allocation): ``malleable`` marks a mid-run
             # resize, which is the delta metrics consumers add to the
@@ -636,8 +633,6 @@ class JobQueue:
                                     _path_type_counts(self, job))
             self._sync_alloc_meta(job.alloc_id)
             self._version += 1
-            self._log(f"t={self.clock.now():.3f} shrink {job.jobid} "
-                      f"-{len(doomed)}")
             self.eventlog.emit(EventType.SHRINK, job.jobid,
                                n_paths=len(doomed), alloc_id=job.alloc_id)
             return True
@@ -698,8 +693,6 @@ class JobQueue:
         bisect.insort(self.pending, job, key=self.policy.sort_key)
         self._pmirror.add(job)
         self._version += 1
-        self._log(f"t={now:.3f} preempt {job.jobid} "
-                  f"(n={job.preemptions})")
         self.eventlog.emit(EventType.PREEMPT, job.jobid,
                            alloc_id=job.alloc_id, n=job.preemptions)
 

@@ -30,7 +30,6 @@ Figures 1/3/4 and its analytical model (Section 6):
 from __future__ import annotations
 
 import itertools
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
@@ -41,6 +40,7 @@ from .external import ExternalProvider
 from .graph import ResourceGraph
 from .jobspec import Jobspec
 from .match import Matcher
+from .metrics import NO_SPAN
 from .rpc import (InProcTransport, MethodRegistry, MuxServer,
                   SocketTransport, Transport, pack_json, unpack_json)
 from .transform import TransformKind, TransformResult, remove_subgraph
@@ -92,11 +92,9 @@ class SchedulerInstance:
         # or Instance: RELEASE is emitted here, GROW/REVOKE by the
         # engine.  Scheduler-level events are keyed by allocation id.
         self.eventlog = None
-        # optional trace-span sink (core/metrics.py SpanCollector or
-        # anything with .record(dict)): the engine records per-stage
-        # match_grow spans and release() records release spans.  None
-        # (the default) costs producers one attribute check.
-        self.span_collector = None
+        # matches and matches that found an allocation (always on)
+        self.n_matches = 0
+        self.n_match_hits = 0
         # per-instance mutation lock: RPCServer sessions run in their
         # own threads and SocketTransport pools connections, so
         # concurrent MG/release/revoke requests can hit one instance at
@@ -122,6 +120,21 @@ class SchedulerInstance:
     # ------------------------------------------------------------------ #
     # serving (parent side)
     # ------------------------------------------------------------------ #
+
+    @property
+    def span_collector(self):
+        """Optional trace-span sink (``core/metrics.SpanCollector``): the
+        one switch of the scheduling plane's spans.  Setting it hands
+        it to the graph (read by the matcher and the flat mirror) and
+        the event log; the queue, the policy and the engine read it
+        here.  None (the default) costs each span site one check."""
+        return self.graph.span_collector
+
+    @span_collector.setter
+    def span_collector(self, col) -> None:
+        self.graph.span_collector = col
+        if self.eventlog is not None:
+            self.eventlog.span_collector = col
     def serve(self, backlog: int = 512, workers: int = 8
               ) -> Tuple[str, int]:
         """Expose this instance over a loopback socket ("internode").
@@ -219,9 +232,14 @@ class SchedulerInstance:
         with self.lock:
             matcher = Matcher(self.graph)
             paths = matcher.match(jobspec)
+            self.n_matches += 1
             if paths is None:
                 return None
-            self.graph.set_allocated(paths, jobid)
+            self.n_match_hits += 1
+            col = self.graph.span_collector
+            with NO_SPAN if col is None else col.span(
+                    "alloc", n_paths=len(paths)):
+                self.graph.set_allocated(paths, jobid)
             alloc = self.allocations.setdefault(jobid, Allocation(jobid))
             alloc.paths.extend(paths)
             return alloc
@@ -286,16 +304,14 @@ class SchedulerInstance:
         shrink and free operations); the record happens after every
         lock is released.
         """
-        col = self.span_collector
+        col = self.graph.span_collector
         if col is None:
             self._release(jobid, paths)
             return
-        t0 = time.perf_counter()
-        n = self._release(jobid, paths)
-        col.record({"name": "release", "level": self.name,
-                    "jobid": jobid, "ok": n > 0, "via": None,
-                    "dur": time.perf_counter() - t0,
-                    "stages": {}, "n_paths": n})
+        with col.span("release", level=self.name, jobid=jobid,
+                      via=None, stages={}) as sp:
+            n = self._release(jobid, paths)
+            sp.attrs.update(ok=n > 0, n_paths=n)
 
     def _release(self, jobid: str,
                  paths: Optional[Sequence[str]] = None) -> int:

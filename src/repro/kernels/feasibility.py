@@ -120,6 +120,51 @@ def _feasible_pallas(tid, msize, rmlo, rmhi, need,
 # ---------------------------------------------------------------------- #
 # dispatch (the kernels/ops.py idiom)
 # ---------------------------------------------------------------------- #
+def pack_inputs(vtype: np.ndarray, vok: np.ndarray, vsize: np.ndarray,
+                vmask: np.ndarray, agg: np.ndarray,
+                tid: np.ndarray, msize: np.ndarray, rmask: np.ndarray,
+                need: np.ndarray,
+                use_pallas: str = "auto") -> Tuple[str, tuple]:
+    """The host half of :func:`batched_feasible_op`: the path it takes
+    (``'xla'``, ``'pallas'`` or ``'interpret'``) and the arguments of
+    that path's jitted program, cast to int32, property masks split,
+    and for the kernel padded to its blocks.  Their ``nbytes`` are what
+    the call copies to the device."""
+    vmlo, vmhi = _split_mask(vmask)
+    rmlo, rmhi = _split_mask(rmask)
+    vtype = np.asarray(vtype, np.int32)
+    vok = np.asarray(vok, np.int32)
+    vsize = np.asarray(vsize, np.int32)
+    agg = np.asarray(agg, np.int32)
+    tid = np.asarray(tid, np.int32)
+    msize = np.asarray(msize, np.int32)
+    need = np.asarray(need, np.int32)
+    if use_pallas == "xla" or (use_pallas == "auto"
+                               and _backend() != "tpu"):
+        return "xla", (vtype, vok, vsize, vmlo, vmhi, agg,
+                       tid, msize, rmlo, rmhi, need)
+    # pad request rows, vertex lanes, and the type sublane; padded
+    # vertices carry vok=0 (never feasible) and padded types need=0
+    # against agg=0 (vacuously satisfied)
+    rcol = lambda a: _pad(a.reshape(-1, 1), 0, _BN)             # noqa: E731
+    vrow = lambda a: _pad(a.reshape(1, -1), 1, _BV)             # noqa: E731
+    path = "interpret" if use_pallas == "interpret" else "pallas"
+    return path, (rcol(tid), rcol(msize), rcol(rmlo), rcol(rmhi),
+                  _pad(_pad(need, 0, _BN), 1, 8),
+                  vrow(vtype), vrow(vok), vrow(vsize), vrow(vmlo),
+                  vrow(vmhi), _pad(_pad(agg.T, 0, 8), 1, _BV))
+
+
+def run_packed(path: str, args: tuple) -> np.ndarray:
+    """The device half: dispatch the packed call and pull the whole
+    int32 mask back to the host (padded on the kernel paths; rows are
+    requests, columns vertices)."""
+    if path == "xla":
+        return np.asarray(_ref_batched_feasible(*args))
+    return np.asarray(_feasible_pallas(*args,
+                                       interpret=path == "interpret"))
+
+
 def batched_feasible_op(vtype: np.ndarray, vok: np.ndarray,
                         vsize: np.ndarray, vmask: np.ndarray,
                         agg: np.ndarray,
@@ -133,31 +178,6 @@ def batched_feasible_op(vtype: np.ndarray, vok: np.ndarray,
     ``use_pallas``: ``'auto'`` (compiled kernel on TPU, XLA elsewhere),
     ``'pallas'`` (compiled kernel; fails off TPU), ``'interpret'``,
     ``'xla'``."""
-    vmlo, vmhi = _split_mask(vmask)
-    rmlo, rmhi = _split_mask(rmask)
-    vtype = np.asarray(vtype, np.int32)
-    vok = np.asarray(vok, np.int32)
-    vsize = np.asarray(vsize, np.int32)
-    agg = np.asarray(agg, np.int32)
-    tid = np.asarray(tid, np.int32)
-    msize = np.asarray(msize, np.int32)
-    need = np.asarray(need, np.int32)
-    if use_pallas == "xla" or (use_pallas == "auto"
-                               and _backend() != "tpu"):
-        return np.asarray(_ref_batched_feasible(
-            vtype, vok, vsize, vmlo, vmhi, agg,
-            tid, msize, rmlo, rmhi, need))
-    interpret = use_pallas == "interpret"
-    n, v = tid.shape[0], vtype.shape[0]
-    # pad request rows, vertex lanes, and the type sublane; padded
-    # vertices carry vok=0 (never feasible) and padded types need=0
-    # against agg=0 (vacuously satisfied)
-    rcol = lambda a: _pad(a.reshape(-1, 1), 0, _BN)             # noqa: E731
-    vrow = lambda a: _pad(a.reshape(1, -1), 1, _BV)             # noqa: E731
-    out = _feasible_pallas(
-        rcol(tid), rcol(msize), rcol(rmlo), rcol(rmhi),
-        _pad(_pad(need, 0, _BN), 1, 8),
-        vrow(vtype), vrow(vok), vrow(vsize), vrow(vmlo), vrow(vmhi),
-        _pad(_pad(agg.T, 0, 8), 1, _BV),
-        interpret=interpret)
-    return np.asarray(out)[:n, :v]
+    out = run_packed(*pack_inputs(vtype, vok, vsize, vmask, agg, tid,
+                                  msize, rmask, need, use_pallas))
+    return out[:len(tid), :len(vtype)]

@@ -128,8 +128,7 @@ def test_easy_vs_conservative_admission_on_contended_trace():
         assert s.completed == s.submitted
         assert q.scheduler.allocations == {}
         assert q.scheduler.graph.validate_tree()
-        backfills = sum(1 for line in q.events if " backfill " in line)
-        return backfills, s
+        return q.n_backfilled, s
 
     bf_refined, s_refined = replay(make_policy("easy"))
     bf_strict, s_strict = replay(EasyBackfill(spare_capacity=False))
@@ -452,8 +451,7 @@ def _replay_easy(policy, trace, nodes=4):
     assert s.completed == s.submitted
     assert q.scheduler.allocations == {}
     starts = {j.jobid: j.start_time for j in q.completed}
-    backfills = sum(1 for line in q.events if " backfill " in line)
-    return starts, backfills, s
+    return starts, q.n_backfilled, s
 
 
 def test_ledger_estimators_equal_legacy_walk():
