@@ -161,15 +161,20 @@ def _causal_mask(sq: int, skv: int, q_offset, window: int = 0) -> jax.Array:
 def attention(x: jax.Array, p: Dict, cfg: ArchConfig, ctx: ShardingCtx,
               positions: jax.Array,
               cache: Optional[Dict] = None,
-              cache_index: Optional[jax.Array] = None,
               window: int = 0,
               want_cache: bool = False) -> Tuple[jax.Array, Optional[Dict]]:
     """GQA attention.
 
+    K/V caches are head-major, [b, kvh, S, d] per layer, so a layer's
+    cache feeds the attention contractions as it lies in memory.
+
     Train/prefill: ``x`` is [b, s, e] (sequence-sharded over 'model'),
     cache is None (prefill returns the fresh cache).
-    Decode: ``x`` is [b, 1, e]; ``cache`` holds k/v [b, S, kvh, d]
-    sequence-sharded over 'model'; ``cache_index`` is the write position.
+    Decode: ``x`` is [b, 1, e]; ``cache`` holds this layer's k/v
+    (sequence-sharded over 'model'), read only: the step attends over the
+    cached positions before ``positions`` and over its own new key and
+    value, and returns that row ({"k", "v"} [b, kvh, 1, d] in the cache's
+    dtype) for the caller to write at ``positions``.
     """
     b, s, e = x.shape
     h, kvh, d = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -186,31 +191,22 @@ def attention(x: jax.Array, p: Dict, cfg: ArchConfig, ctx: ShardingCtx,
         q = apply_rope(q, positions, cfg.rope_theta, msecs)
         k = apply_rope(k, positions, cfg.rope_theta, msecs)
 
-    new_cache = None
-    if cache is not None:                      # decode: append to cache
-        ck, cv = cache["k"], cache["v"]
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, cache_index, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, cache_index, 0, 0))
-        ck = constrain(ck, ctx, "batch", "kv_seq", "kv_heads", "head_dim")
-        cv = constrain(cv, ctx, "batch", "kv_seq", "kv_heads", "head_dim")
-        new_cache = {"k": ck, "v": cv}
-        k, v = ck.astype(cdt), cv.astype(cdt)
-        skv = k.shape[1]
-        kpos = jnp.arange(skv)
-        ppos = positions if positions.ndim == 2 else positions[0]  # mrope: t
-        mask = kpos[None, :] <= ppos[:, :1]                  # [b, skv]
-        if window:
-            mask = jnp.logical_and(mask, kpos[None, :] > ppos[:, :1] - window)
-        mask = mask[:, None, None, None, :]                  # [b,1,1,1,skv]
-    else:
-        skv = s
-        mask = _causal_mask(s, skv, 0, window)[None, None, None, :, :]
-        if want_cache:
-            kc = constrain(k, ctx, "batch", "kv_seq", "kv_heads", "head_dim")
-            vc = constrain(v, ctx, "batch", "kv_seq", "kv_heads", "head_dim")
-            new_cache = {"k": kc, "v": vc}
-
     g = h // kvh
+    if cache is not None:
+        o, new_row = _decode_attention(q.reshape(b, kvh, g, d),
+                                       k.reshape(b, kvh, 1, d),
+                                       v.reshape(b, kvh, 1, d),
+                                       cache, ctx, positions, window)
+        return o.reshape(b, s, h * d) @ p["wo"].astype(cdt), new_row
+
+    new_cache = None
+    if want_cache:
+        kc = constrain(k.transpose(0, 2, 1, 3), ctx,
+                       "batch", "kv_heads", "kv_seq", "head_dim")
+        vc = constrain(v.transpose(0, 2, 1, 3), ctx,
+                       "batch", "kv_heads", "kv_seq", "head_dim")
+        new_cache = {"k": kc, "v": vc}
+    mask = _causal_mask(s, s, 0, window)[None, None, None, :, :]
     qg = q.reshape(b, s, kvh, g, d)
     # scores: [b, kvh, g, sq, skv]
     scores = jnp.einsum("bsknd,btkd->bknst", qg, k).astype(jnp.float32)
@@ -220,6 +216,41 @@ def attention(x: jax.Array, p: Dict, cfg: ArchConfig, ctx: ShardingCtx,
     o = jnp.einsum("bknst,btkd->bsknd", w, v).reshape(b, s, h * d)
     out = o @ p["wo"].astype(cdt)
     return out, new_cache
+
+
+def _decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                      cache: Dict, ctx: ShardingCtx, positions: jax.Array,
+                      window: int) -> Tuple[jax.Array, Dict]:
+    """One query position over the cache and its own key/value row.
+
+    q [b, kvh, g, d]; k, v [b, kvh, 1, d]; cache k/v [b, kvh, S, d].
+    The cached positions before the query's and the new row are the keys
+    a cache with the row written in would give (positions <= the
+    query's, within the window); their float32 scores share one softmax.
+    Returns (o [b, kvh, g, d], {"k", "v"} rows in the cache's dtype)."""
+    ck = constrain(cache["k"], ctx, "batch", "kv_heads", "kv_seq", "head_dim")
+    cv = constrain(cache["v"], ctx, "batch", "kv_heads", "kv_seq", "head_dim")
+    row = {"k": k.astype(ck.dtype), "v": v.astype(cv.dtype)}
+    cdt = q.dtype
+    k, v = row["k"].astype(cdt), row["v"].astype(cdt)
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    skv = ck.shape[2]
+    kpos = jnp.arange(skv)
+    ppos = positions if positions.ndim == 2 else positions[0]  # mrope: t
+    mask = kpos[None, :] < ppos[:, :1]                   # [b, skv]
+    if window:
+        mask = jnp.logical_and(mask, kpos[None, :] > ppos[:, :1] - window)
+    # scores: [b, kvh, g, skv] over the cache, [b, kvh, g, 1] over the row
+    sc = jnp.einsum("bknd,bktd->bknt", q, ck.astype(cdt)).astype(jnp.float32)
+    sn = jnp.einsum("bknd,bktd->bknt", q, k).astype(jnp.float32)
+    sc = jnp.where(mask[:, None, None, :], sc * scale, -1e30)
+    w = jax.nn.softmax(jnp.concatenate([sc, sn * scale], axis=-1),
+                       axis=-1).astype(cdt)
+    o = jnp.einsum("bknt,bktd->bknd", w[..., :skv], cv.astype(cdt),
+                   preferred_element_type=jnp.float32) + \
+        jnp.einsum("bknt,bktd->bknd", w[..., skv:], v,
+                   preferred_element_type=jnp.float32)
+    return o.astype(cdt), row
 
 
 # ---------------------------------------------------------------------- #

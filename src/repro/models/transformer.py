@@ -208,10 +208,11 @@ def loss_fn(params: Dict, cfg: ArchConfig, ctx: ShardingCtx,
 # ---------------------------------------------------------------------- #
 def init_cache_specs(cfg: ArchConfig, batch: int, seq: int,
                      dtype=jnp.bfloat16) -> Dict[str, Any]:
-    """ShapeDtypeStructs for the decode cache."""
+    """ShapeDtypeStructs for the decode cache (K/V head-major:
+    [layers, b, kvh, S, d])."""
     groups, per = _group_layout(cfg)
     h = jax.ShapeDtypeStruct
-    kvd = (batch, seq, cfg.n_kv_heads, cfg.hd)
+    kvd = (batch, cfg.n_kv_heads, seq, cfg.hd)
     if cfg.family == "ssm":
         st = mamba_state_specs(cfg, batch)
         return {k: h((cfg.n_layers,) + v.shape, v.dtype) for k, v in st.items()}
@@ -236,15 +237,30 @@ def cache_shardings(cfg: ArchConfig, ctx: ShardingCtx):
     if cfg.family == "ssm":
         return {"conv": sh("layers", "batch", None, None),
                 "ssm": sh("layers", "batch", "ssm_heads", None, None)}
-    kv = sh("layers", "batch", "kv_seq", "kv_heads", "head_dim")
+    kv = sh("layers", "batch", "kv_heads", "kv_seq", "head_dim")
     if cfg.family == "hybrid":
         return {"conv": sh("layers", "batch", None, None),
                 "ssm": sh("layers", "batch", "ssm_heads", None, None),
                 "shared_k": kv, "shared_v": kv}
     if cfg.is_moe and cfg.moe_every > 1:
-        kv2 = sh("layers", None, "batch", "kv_seq", "kv_heads", "head_dim")
+        kv2 = sh("layers", None, "batch", "kv_heads", "kv_seq", "head_dim")
         return {"k": kv2, "v": kv2}
     return {"k": kv, "v": kv}
+
+
+def _layer(stack: jax.Array, i: jax.Array) -> jax.Array:
+    """Layer ``i`` of a stacked cache, read in place inside the scan."""
+    return jax.lax.dynamic_index_in_dim(stack, i, 0, keepdims=False)
+
+
+def _write_rows(stack: jax.Array, rows: jax.Array, pos) -> jax.Array:
+    """Write the step's rows [..., b, kvh, 1, d] into the stacked cache
+    [..., b, kvh, S, d] at sequence index ``pos``: one update of the
+    donated buffer, in place."""
+    start = [0] * stack.ndim
+    start[stack.ndim - 2] = pos
+    return jax.lax.dynamic_update_slice(stack, rows.astype(stack.dtype),
+                                        tuple(start))
 
 
 def decode_step(params: Dict, cache: Dict, cfg: ArchConfig, ctx: ShardingCtx,
@@ -253,7 +269,12 @@ def decode_step(params: Dict, cache: Dict, cfg: ArchConfig, ctx: ShardingCtx,
                 pos: jax.Array = None):
     """One decode step.  tokens [b, 1] (or embeds [b, 1, e]); ``pos`` is
     the scalar write position (current context length).  Returns
-    (logits [b, 1, v], new_cache)."""
+    (logits [b, 1, v], new_cache).
+
+    The stacked K/V caches never pass through the layer scan as
+    ``xs``/``ys``: each layer reads its slice of the closed-over stack,
+    the scan emits only the new rows, and the rows are written into the
+    stack once after it, so a donated cache is updated in place."""
     if embeds is not None:
         x = embeds.astype(jnp.dtype(cfg.dtype))
         b = embeds.shape[0]
@@ -277,6 +298,11 @@ def decode_step(params: Dict, cache: Dict, cfg: ArchConfig, ctx: ShardingCtx,
         logits = lm_logits(x, params["embed"], cfg, ctx)
         return logits, new_states
 
+    def attend(x, ap, i, ks, vs):
+        a, row = attention(x, ap, cfg, ctx, positions,
+                           cache={"k": _layer(ks, i), "v": _layer(vs, i)})
+        return x + a, row
+
     if cfg.family == "hybrid":
         mam = jax.tree_util.tree_map(
             lambda a: a.reshape((groups, per) + a.shape[1:]),
@@ -285,56 +311,48 @@ def decode_step(params: Dict, cache: Dict, cfg: ArchConfig, ctx: ShardingCtx,
             lambda a: a.reshape((groups, per) + a.shape[1:]), params["blocks"])
 
         def body(x, sc):
-            bp, st, sk, sv = sc
+            bp, st, i = sc
             def inner(x, sub):
                 subp, subst = sub
                 y, nst = mamba_layer(x, subp, cfg, ctx, state=subst)
                 return x + y, nst
             x, new_st = jax.lax.scan(inner, x, (bp, st))
-            a, kvc = attention(x, params["shared"]["attn"], cfg, ctx,
-                               positions, cache={"k": sk, "v": sv},
-                               cache_index=pos)
-            x = x + a
+            x, row = attend(x, params["shared"]["attn"], i,
+                            cache["shared_k"], cache["shared_v"])
             x = x + mlp(x, params["shared"]["mlp"], cfg, ctx)
-            return x, (new_st, kvc["k"], kvc["v"])
-        x, (new_st, nk, nv) = jax.lax.scan(body, x, (blocks, mam,
-                                                     cache["shared_k"],
-                                                     cache["shared_v"]))
+            return x, (new_st, row)
+        x, (new_st, rows) = jax.lax.scan(body, x, (blocks, mam,
+                                                   jnp.arange(groups)))
         flat = jax.tree_util.tree_map(
             lambda a: a.reshape((groups * per,) + a.shape[2:]), new_st)
         logits = lm_logits(x, params["embed"], cfg, ctx)
         return logits, {"conv": flat["conv"], "ssm": flat["ssm"],
-                        "shared_k": nk, "shared_v": nv}
+                        "shared_k": _write_rows(cache["shared_k"],
+                                                rows["k"], pos),
+                        "shared_v": _write_rows(cache["shared_v"],
+                                                rows["v"], pos)}
 
     if cfg.is_moe and cfg.moe_every > 1:
         def body(x, sc):
-            bp, ck, cv = sc
-            a, kv1 = attention(x, bp["dense"]["attn"], cfg, ctx, positions,
-                               cache={"k": ck[0], "v": cv[0]}, cache_index=pos)
-            x = x + a
+            bp, i = sc
+            ks, vs = _layer(cache["k"], i), _layer(cache["v"], i)
+            x, row1 = attend(x, bp["dense"]["attn"], 0, ks, vs)
             x = x + mlp(x, bp["dense"]["mlp"], cfg, ctx)
-            a2, kv2 = attention(x, bp["moe"]["attn"], cfg, ctx, positions,
-                                cache={"k": ck[1], "v": cv[1]}, cache_index=pos)
-            x = x + a2
+            x, row2 = attend(x, bp["moe"]["attn"], 1, ks, vs)
             x = x + moe(x, bp["moe"]["ffn"], cfg, ctx)
-            nk = jnp.stack([kv1["k"], kv2["k"]])
-            nv = jnp.stack([kv1["v"], kv2["v"]])
-            return x, (nk, nv)
-        x, (nk, nv) = jax.lax.scan(body, x, (params["blocks"],
-                                             cache["k"], cache["v"]))
-        logits = lm_logits(x, params["embed"], cfg, ctx)
-        return logits, {"k": nk, "v": nv}
-
-    def body(x, sc):
-        bp, ck, cv = sc
-        a, kvc = attention(x, bp["attn"], cfg, ctx, positions,
-                           cache={"k": ck, "v": cv}, cache_index=pos)
-        x = x + a
-        ffn = moe(x, bp["ffn"], cfg, ctx) if cfg.is_moe \
-            else mlp(x, bp["mlp"], cfg, ctx)
-        x = x + ffn
-        return x, (kvc["k"], kvc["v"])
-    x, (nk, nv) = jax.lax.scan(body, x, (params["blocks"],
-                                         cache["k"], cache["v"]))
+            return x, jax.tree_util.tree_map(
+                lambda r1, r2: jnp.stack([r1, r2]), row1, row2)
+        x, rows = jax.lax.scan(body, x, (params["blocks"],
+                                         jnp.arange(groups)))
+    else:
+        def body(x, sc):
+            bp, i = sc
+            x, row = attend(x, bp["attn"], i, cache["k"], cache["v"])
+            ffn = moe(x, bp["ffn"], cfg, ctx) if cfg.is_moe \
+                else mlp(x, bp["mlp"], cfg, ctx)
+            return x + ffn, row
+        x, rows = jax.lax.scan(body, x, (params["blocks"],
+                                         jnp.arange(cfg.n_layers)))
     logits = lm_logits(x, params["embed"], cfg, ctx)
-    return logits, {"k": nk, "v": nv}
+    return logits, {"k": _write_rows(cache["k"], rows["k"], pos),
+                    "v": _write_rows(cache["v"], rows["v"], pos)}
