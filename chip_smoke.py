@@ -21,9 +21,11 @@ Phases:
 * **serving** — ``launch/serve.run_serving`` for phi4-mini-3.8b at its
   registered widths and depth with random bf16 weights; the logits of
   the decode-through-cache steps must match one full forward pass.
-* **elastic** (``--chips 4``) — ``ElasticRuntime`` training at
-  published widths, 2 chips -> grow to 4 -> shrink to 2 through the
-  ``Instance`` queue, against the same batches on one fixed device.
+* **elastic** (``--chips 4``) — ``ElasticRuntime`` training of
+  musicgen-medium at published widths (codes of four codebooks in the
+  delay pattern, text states and their mask), 2 chips -> grow to 4 ->
+  shrink to 2 through the ``Instance`` queue, against the same batches
+  on one fixed device.
 """
 from __future__ import annotations
 
@@ -389,7 +391,8 @@ def elastic_phase(arch: str = "musicgen-medium", n_layers: int = 16,
     assert all(np.isfinite(losses)), losses
     np.testing.assert_allclose(losses, ref_losses, rtol=ELASTIC_LOSS_RTOL)
     return {"losses": losses, "ref_losses": ref_losses, "stages": stages,
-            "events": kinds, "compile_s": comp["compile_s"], "wall_s": wall}
+            "events": kinds, "inputs": sorted(pipe.batch_at(0)),
+            "compile_s": comp["compile_s"], "wall_s": wall}
 
 
 # ---------------------------------------------------------------------- #

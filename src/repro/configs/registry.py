@@ -32,7 +32,11 @@ def shapes_for(cfg: ArchConfig) -> List[ShapeConfig]:
 
     ``long_500k`` needs sub-quadratic attention: it runs only for the
     SSM/hybrid archs (mamba2, zamba2) and is SKIPPED for the 8 pure
-    full-attention archs (documented in DESIGN.md §Shape skips)."""
+    full-attention archs (documented in DESIGN.md §Shape skips).  A
+    codebook model (musicgen) trains only: serving it needs a
+    cross-attention cache the program does not have."""
+    if cfg.is_codebook:
+        return [SHAPES["train_4k"]]
     out = [SHAPES["train_4k"], SHAPES["prefill_32k"], SHAPES["decode_32k"]]
     if cfg.family in ("ssm", "hybrid"):
         out.append(SHAPES["long_500k"])

@@ -18,7 +18,9 @@ class ArchConfig:
     d_ff: int
     vocab: int
     head_dim: int = 0                # 0 -> d_model // n_heads
-    mlp_act: str = "swiglu"          # swiglu | relu2 | gelu
+    mlp_act: str = "swiglu"          # swiglu | relu2 | gelu (tanh form)
+    #   | gelu_exact (erf form)
+    norm: str = "rms"                # rms | layernorm (weight and bias)
     rope: str = "rope"               # rope | mrope | none
     rope_theta: float = 10_000.0
     # MoE
@@ -37,8 +39,15 @@ class ArchConfig:
     ssm_groups: int = 1
     # hybrid (Zamba2-style): shared attention block every k SSM layers
     shared_attn_every: int = 0
-    # frontend: token | audio_stub | vision_stub
+    # frontend: token | codebooks | vision_stub
     frontend: str = "token"
+    # codebooks (MusicGen): ``n_codebooks`` parallel token streams of
+    # ``vocab`` codes each plus one special token (id ``vocab``), in the
+    # delay pattern; summed embeddings in, one head per codebook out
+    n_codebooks: int = 0
+    # cross-attention in every layer to conditioning states of width
+    # ``cond_dim`` (projected to d_model, with bias); 0 = none
+    cond_dim: int = 0
     # attention
     sliding_window: int = 0          # 0 = full causal
     # numerics
@@ -97,6 +106,10 @@ class ArchConfig:
         return self.d_inner // self.ssm_head_dim if self.ssm_state else 0
 
     @property
+    def is_codebook(self) -> bool:
+        return self.n_codebooks > 0
+
+    @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
@@ -107,8 +120,15 @@ class ArchConfig:
     def n_params(self) -> int:
         """Approximate parameter count (for roofline MODEL_FLOPS)."""
         e, hd = self.d_model, self.hd
-        total = self.vocab * e * (1 if self.tie_embeddings else 2)
+        if self.is_codebook:        # K embeddings (codes + special), K heads
+            k = self.n_codebooks
+            total = k * (self.vocab + 1) * e + k * e * self.vocab
+        else:
+            total = self.vocab * e * (1 if self.tie_embeddings else 2)
         per_attn = e * (self.n_heads * hd) * 2 + e * (self.n_kv_heads * hd) * 2
+        if self.cond_dim:           # cross-attention, and the projection
+            per_attn += 4 * e * self.n_heads * hd
+            total += self.cond_dim * e + e
         mlp_mults = 3 if self.mlp_act == "swiglu" else 2
         per_dense_mlp = mlp_mults * e * self.d_ff
         per_moe = self.n_experts * mlp_mults * e * self.expert_ff
@@ -154,7 +174,9 @@ class ArchConfig:
             if not self.shared_attn_every else 4,
             d_model=64,
             n_heads=4,
-            n_kv_heads=min(self.n_kv_heads, 2),
+            # MusicGen's attention is multi-head: keep every head's K/V
+            n_kv_heads=4 if self.is_codebook else min(self.n_kv_heads, 2),
+            cond_dim=32 if self.cond_dim else 0,
             head_dim=16,
             d_ff=128,
             moe_d_ff=32 if self.is_moe else 0,
@@ -179,6 +201,8 @@ class ShapeConfig:
     seq_len: int
     global_batch: int
     mode: str  # train | prefill | decode
+    cond_len: int = 64   # padded length of the conditioning states
+    #   (models with cross-attention only)
 
     @property
     def tokens(self) -> int:

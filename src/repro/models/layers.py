@@ -1,4 +1,5 @@
-"""Model building blocks: norms, rotary embeddings, GQA attention, MLPs.
+"""Model building blocks: norms, rotary embeddings, GQA attention,
+cross-attention, MLPs, token and codebook embeddings and heads.
 
 Pure-functional JAX.  Every layer takes a ``ShardingCtx`` so activation
 sharding constraints are expressed with logical axis names (see
@@ -80,6 +81,34 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-5) -> jax.Array:
     return ((x32 * jax.lax.rsqrt(var + eps)) * (1.0 + w.astype(jnp.float32))).astype(dt)
 
 
+def layernorm(x: jax.Array, w: jax.Array, b: jax.Array,
+              eps: float = 1e-5) -> jax.Array:
+    """LayerNorm with weight and bias; the weight is stored as an offset
+    from 1, as the RMS norm's is."""
+    dt = x.dtype
+    x32 = x.astype(jnp.float32)
+    xc = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    return (xc * jax.lax.rsqrt(var + eps) * (1.0 + w.astype(jnp.float32))
+            + b.astype(jnp.float32)).astype(dt)
+
+
+def norm(x: jax.Array, p: Dict, cfg: ArchConfig,
+         key: str = "norm") -> jax.Array:
+    """The configuration's pre-norm, with parameters ``p[key]`` (and the
+    LayerNorm's bias ``p[key + "_b"]``)."""
+    if cfg.norm == "layernorm":
+        return layernorm(x, p[key], p[key + "_b"], cfg.norm_eps)
+    return rmsnorm(x, p[key], cfg.norm_eps)
+
+
+def norm_specs(cfg: ArchConfig, key: str = "norm") -> Dict[str, ParamSpec]:
+    specs = {key: ParamSpec((cfg.d_model,), (None,), init="zeros")}
+    if cfg.norm == "layernorm":
+        specs[key + "_b"] = ParamSpec((cfg.d_model,), (None,), init="zeros")
+    return specs
+
+
 # ---------------------------------------------------------------------- #
 # rotary embeddings (RoPE and M-RoPE)
 # ---------------------------------------------------------------------- #
@@ -137,7 +166,7 @@ def attn_specs(cfg: ArchConfig) -> Dict[str, ParamSpec]:
         "wk": ParamSpec((e, kvh * d), ("fsdp2d", None)),
         "wv": ParamSpec((e, kvh * d), ("fsdp2d", None)),
         "wo": ParamSpec((h * d, e), ("fsdp2d", None)),
-        "norm": ParamSpec((e,), (None,), init="zeros"),
+        **norm_specs(cfg),
     }
 
 
@@ -179,7 +208,7 @@ def attention(x: jax.Array, p: Dict, cfg: ArchConfig, ctx: ShardingCtx,
     b, s, e = x.shape
     h, kvh, d = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     window = window or cfg.sliding_window
-    xn = rmsnorm(x, p["norm"], cfg.norm_eps)
+    xn = norm(x, p, cfg)
     cdt = xn.dtype
 
     q = (xn @ p["wq"].astype(cdt)).reshape(b, s, h, d)
@@ -187,7 +216,7 @@ def attention(x: jax.Array, p: Dict, cfg: ArchConfig, ctx: ShardingCtx,
     v = (xn @ p["wv"].astype(cdt)).reshape(b, s, kvh, d)
 
     msecs = mrope_sections_for(d) if cfg.rope == "mrope" else None
-    if cfg.rope != "none":
+    if cfg.rope in ("rope", "mrope"):    # abs_sin adds positions to x
         q = apply_rope(q, positions, cfg.rope_theta, msecs)
         k = apply_rope(k, positions, cfg.rope_theta, msecs)
 
@@ -254,6 +283,59 @@ def _decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 # ---------------------------------------------------------------------- #
+# cross-attention to conditioning states (MusicGen's text)
+# ---------------------------------------------------------------------- #
+def xattn_specs(cfg: ArchConfig) -> Dict[str, ParamSpec]:
+    """Q from the stream, K/V from the projected conditioning states
+    (width d_model), O back; every head attends (no grouping)."""
+    e, h, d = cfg.d_model, cfg.n_heads, cfg.hd
+    return {
+        "wq": ParamSpec((e, h * d), ("fsdp2d", None)),
+        "wk": ParamSpec((e, h * d), ("fsdp2d", None)),
+        "wv": ParamSpec((e, h * d), ("fsdp2d", None)),
+        "wo": ParamSpec((h * d, e), ("fsdp2d", None)),
+        **norm_specs(cfg),
+    }
+
+
+def cond_specs(cfg: ArchConfig) -> Dict[str, ParamSpec]:
+    """Projection of the conditioning states to the model width."""
+    return {"w": ParamSpec((cfg.cond_dim, cfg.d_model), (None, "fsdp")),
+            "b": ParamSpec((cfg.d_model,), (None,), init="zeros")}
+
+
+def project_cond(cond: jax.Array, p: Dict, cfg: ArchConfig,
+                 ctx: ShardingCtx) -> jax.Array:
+    """cond [b, t, cond_dim] -> [b, t, d_model]: cond @ w + b."""
+    cdt = jnp.dtype(cfg.dtype)
+    out = cond.astype(cdt) @ p["w"].astype(cdt) + p["b"].astype(cdt)
+    return constrain(out, ctx, "batch", None, "embed")
+
+
+def cross_attention(x: jax.Array, p: Dict, cond: jax.Array,
+                    cond_mask: jax.Array, cfg: ArchConfig,
+                    ctx: ShardingCtx) -> jax.Array:
+    """Pre-norm cross-attention of ``x`` [b, s, e] over the projected
+    conditioning states ``cond`` [b, t, e]: no causal mask, and key
+    positions where ``cond_mask`` [b, t] is False (padding) are left
+    out of every softmax."""
+    b, s, _ = x.shape
+    t = cond.shape[1]
+    h, d = cfg.n_heads, cfg.hd
+    xn = norm(x, p, cfg)
+    cdt = xn.dtype
+    q = (xn @ p["wq"].astype(cdt)).reshape(b, s, h, d)
+    k = (cond @ p["wk"].astype(cdt)).reshape(b, t, h, d)
+    v = (cond @ p["wv"].astype(cdt)).reshape(b, t, h, d)
+    scores = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32)
+    scores = jnp.where(cond_mask[:, None, None, :], scores / np.sqrt(d),
+                       -1e30)
+    w = jax.nn.softmax(scores, axis=-1).astype(cdt)
+    o = jnp.einsum("bhst,bthd->bshd", w, v).reshape(b, s, h * d)
+    return o @ p["wo"].astype(cdt)
+
+
+# ---------------------------------------------------------------------- #
 # MLPs
 # ---------------------------------------------------------------------- #
 def mlp_specs(cfg: ArchConfig, d_ff: Optional[int] = None) -> Dict[str, ParamSpec]:
@@ -261,7 +343,7 @@ def mlp_specs(cfg: ArchConfig, d_ff: Optional[int] = None) -> Dict[str, ParamSpe
     specs = {
         "w_up": ParamSpec((e, f), ("fsdp", "tp")),
         "w_down": ParamSpec((f, e), ("tp", "fsdp")),
-        "norm": ParamSpec((e,), (None,), init="zeros"),
+        **norm_specs(cfg),
     }
     if cfg.mlp_act == "swiglu":
         specs["w_gate"] = ParamSpec((e, f), ("fsdp", "tp"))
@@ -271,7 +353,7 @@ def mlp_specs(cfg: ArchConfig, d_ff: Optional[int] = None) -> Dict[str, ParamSpe
 def mlp(x: jax.Array, p: Dict, cfg: ArchConfig, ctx: ShardingCtx,
         normed: bool = False) -> jax.Array:
     cdt = x.dtype
-    xn = x if normed else rmsnorm(x, p["norm"], cfg.norm_eps)
+    xn = x if normed else norm(x, p, cfg)
     up = xn @ p["w_up"].astype(cdt)
     if cfg.mlp_seq_sharded:
         # §Perf: keep the [b, s, f] intermediate sequence-sharded so the
@@ -285,6 +367,8 @@ def mlp(x: jax.Array, p: Dict, cfg: ArchConfig, ctx: ShardingCtx,
     elif cfg.mlp_act == "relu2":
         r = jax.nn.relu(up)
         hmid = r * r
+    elif cfg.mlp_act == "gelu_exact":
+        hmid = jax.nn.gelu(up, approximate=False)
     else:
         hmid = jax.nn.gelu(up)
     out = hmid @ p["w_down"].astype(cdt)
@@ -360,3 +444,54 @@ def cross_entropy(logits: jax.Array, labels: jax.Array,
     else:
         gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     return jnp.mean(logz - gold)
+
+
+# ---------------------------------------------------------------------- #
+# codebooks (MusicGen): summed embeddings in, one head per codebook out
+# ---------------------------------------------------------------------- #
+def codebook_specs(cfg: ArchConfig) -> Dict[str, ParamSpec]:
+    """Per codebook an embedding of ``vocab + 1`` rows (the codes and the
+    special token ``vocab``) and a bias-free head to ``vocab`` logits;
+    the final norm."""
+    k, v, e = cfg.n_codebooks, cfg.vocab, cfg.d_model
+    return {
+        "codebooks": ParamSpec((k, v + 1, e), (None, None, "fsdp")),
+        "heads": ParamSpec((k, e, v), (None, "fsdp", "vocab")),
+        **norm_specs(cfg, "final_norm"),
+    }
+
+
+def embed_codes(codes: jax.Array, p: Dict, cfg: ArchConfig,
+                ctx: ShardingCtx) -> jax.Array:
+    """codes [b, K, s] -> the sum over codebooks of each one's embedding
+    of its code, [b, s, e]."""
+    x = sum(jnp.take(p["codebooks"][k], codes[:, k], axis=0)
+            for k in range(cfg.n_codebooks))
+    return constrain(x.astype(jnp.dtype(cfg.dtype)), ctx,
+                     "batch", "seq", "embed")
+
+
+def codebook_logits(x: jax.Array, p: Dict, cfg: ArchConfig,
+                    ctx: ShardingCtx) -> jax.Array:
+    """Final norm, then each codebook's head: logits [b, K, s, vocab]
+    in float32."""
+    xn = norm(x, p, cfg, "final_norm")
+    logits = jnp.einsum("bse,kev->bksv", xn.astype(jnp.float32),
+                        p["heads"].astype(jnp.float32))
+    return constrain(logits, ctx, "batch", None, "seq", "vocab")
+
+
+def codebook_cross_entropy(logits: jax.Array, labels: jax.Array,
+                           special: int) -> jax.Array:
+    """Mean over codebooks of each codebook's token-mean cross-entropy.
+
+    logits [b, K, s, v] float32; labels [b, K, s], where a target equal
+    to ``special`` (the delay pattern's filler) is left out of its
+    codebook's mean."""
+    valid = labels != special
+    logz = jax.scipy.special.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, jnp.where(valid, labels, 0)[..., None],
+                               axis=-1)[..., 0]
+    nll = jnp.where(valid, logz - gold, 0.0)
+    count = jnp.maximum(jnp.sum(valid, axis=(0, 2)), 1)
+    return jnp.mean(jnp.sum(nll, axis=(0, 2)) / count)

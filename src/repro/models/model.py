@@ -102,10 +102,18 @@ class Model:
     def eval_step(self, params, batch: Dict):
         return loss_fn(params, self.cfg, self.ctx, batch)
 
+    def _refuse_codebooks(self, what: str) -> None:
+        if self.cfg.is_codebook:
+            raise NotImplementedError(
+                f"{self.cfg.name}: {what} is not supported for a codebook "
+                f"model (serving it needs a cross-attention cache); it "
+                f"trains only")
+
     def prefill_step(self, params, batch: Dict):
         """Full-context forward returning (last-token logits, cache).
         Only the final position goes through the LM head (§Perf: the
         [b, s, vocab] logits buffer never materializes)."""
+        self._refuse_codebooks("prefill_step")
         mode = "last" if self.cfg.prefill_last_logits else "all"
         logits, cache = forward(params, self.cfg, self.ctx,
                                 tokens=batch.get("tokens"),
@@ -115,6 +123,7 @@ class Model:
 
     def serve_step(self, params, cache, batch: Dict, pos):
         """One decode step: (logits [b,1,v], new cache)."""
+        self._refuse_codebooks("serve_step")
         return decode_step(params, cache, self.cfg, self.ctx,
                            tokens=batch.get("tokens"),
                            embeds=batch.get("embeds"), pos=pos)
@@ -125,11 +134,23 @@ class Model:
     def input_specs(self, shape: ShapeConfig) -> Dict[str, Any]:
         """ShapeDtypeStruct stand-ins for every model input of a cell.
 
-        The audio/vlm modality frontends are stubs: ``input_specs``
-        provides precomputed frame/patch embeddings [b, s, d_model]."""
+        The vlm modality frontend is a stub: ``input_specs`` provides
+        precomputed patch embeddings [b, s, d_model].  A codebook model
+        takes codes [b, K, s] in the delay pattern, its conditioning
+        states [b, t, cond_dim] with their mask [b, t], and labels
+        [b, K, s]."""
         b = shape.global_batch
         s = shape.seq_len if shape.mode != "decode" else 1
         h = jax.ShapeDtypeStruct
+        cfg = self.cfg
+        if cfg.is_codebook:
+            k, t = cfg.n_codebooks, shape.cond_len
+            batch = {"codes": h((b, k, s), jnp.int32),
+                     "labels": h((b, k, s), jnp.int32)}
+            if cfg.cond_dim:
+                batch["cond"] = h((b, t, cfg.cond_dim), jnp.float32)
+                batch["cond_mask"] = h((b, t), jnp.bool_)
+            return batch
         stub = self.cfg.frontend != "token"
         batch: Dict[str, Any] = {}
         if stub:
@@ -143,6 +164,13 @@ class Model:
     def input_shardings(self, shape: ShapeConfig) -> Dict[str, Any]:
         sh = self.ctx.sharding
         seq_ax = "seq" if shape.mode != "decode" else None
+        if self.cfg.is_codebook:
+            out = {"codes": sh("batch", None, seq_ax),
+                   "labels": sh("batch", None, seq_ax)}
+            if self.cfg.cond_dim:
+                out["cond"] = sh("batch", None, "embed")
+                out["cond_mask"] = sh("batch", None)
+            return out
         stub = self.cfg.frontend != "token"
         out: Dict[str, Any] = {}
         if stub:

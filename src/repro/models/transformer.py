@@ -3,7 +3,9 @@
 One code path covers all 10 assigned architectures:
 
 * dense transformers (llama3.2 / phi3 / nemotron / phi4 / musicgen /
-  qwen2-vl backbone) — scan over stacked layers;
+  qwen2-vl backbone) — scan over stacked layers; musicgen's layers also
+  cross-attend to conditioning states, and it reads and predicts four
+  codebooks;
 * MoE (qwen3-moe every layer; llama4-maverick interleaved dense/MoE) —
   scan over stacked groups of ``moe_every`` layers;
 * SSM (mamba2) — scan over stacked Mamba2 blocks;
@@ -26,8 +28,11 @@ import numpy as np
 
 from ..parallel.sharding import ShardingCtx, constrain
 from .config import ArchConfig
-from .layers import (attention, attn_specs, cross_entropy, embed_specs,
-                     embed_tokens, lm_logits, mlp, mlp_specs, stack_specs)
+from .layers import (attention, attn_specs, codebook_cross_entropy,
+                     codebook_logits, codebook_specs, cond_specs,
+                     cross_attention, cross_entropy, embed_codes,
+                     embed_specs, embed_tokens, lm_logits, mlp, mlp_specs,
+                     project_cond, stack_specs, xattn_specs)
 from .mamba2 import mamba_layer, mamba_specs, mamba_state_specs
 from .moe import moe, moe_specs
 
@@ -48,7 +53,10 @@ def _group_layout(cfg: ArchConfig) -> Tuple[int, int]:
 def init_specs(cfg: ArchConfig) -> Dict[str, Any]:
     """The full parameter-spec tree for an architecture."""
     groups, per = _group_layout(cfg)
-    specs: Dict[str, Any] = {"embed": embed_specs(cfg)}
+    specs: Dict[str, Any] = {"embed": codebook_specs(cfg) if cfg.is_codebook
+                             else embed_specs(cfg)}
+    if cfg.cond_dim:
+        specs["cond"] = cond_specs(cfg)
     if cfg.family == "ssm":
         specs["blocks"] = stack_specs(mamba_specs(cfg), cfg.n_layers)
     elif cfg.family == "hybrid":
@@ -64,8 +72,10 @@ def init_specs(cfg: ArchConfig) -> Dict[str, Any]:
         specs["blocks"] = stack_specs(
             {"attn": attn_specs(cfg), "ffn": moe_specs(cfg)}, cfg.n_layers)
     else:
-        specs["blocks"] = stack_specs(
-            {"attn": attn_specs(cfg), "mlp": mlp_specs(cfg)}, cfg.n_layers)
+        layer = {"attn": attn_specs(cfg), "mlp": mlp_specs(cfg)}
+        if cfg.cond_dim:
+            layer["xattn"] = xattn_specs(cfg)
+        specs["blocks"] = stack_specs(layer, cfg.n_layers)
     return specs
 
 
@@ -82,11 +92,13 @@ def make_positions(cfg: ArchConfig, batch: int, seq: int,
 
 
 def _sinusoid(positions: jax.Array, e: int, dtype) -> jax.Array:
-    """Absolute sinusoidal embedding (MusicGen-style), [b, s, e]."""
+    """MusicGen's absolute sinusoidal embedding, [b, s, e]: with
+    h = e/2, [cos(t w_j) for j < h, sin(t w_j) for j < h] where
+    w_j = 10000^(-j/(h-1)); added unscaled."""
     half = e // 2
-    freqs = np.exp(-np.log(10000.0) * np.arange(half) / half)
+    freqs = np.exp(-np.log(10000.0) * np.arange(half) / (half - 1))
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1).astype(dtype)
+    return jnp.concatenate([jnp.cos(ang), jnp.sin(ang)], axis=-1).astype(dtype)
 
 
 # ---------------------------------------------------------------------- #
@@ -96,16 +108,25 @@ def forward(params: Dict, cfg: ArchConfig, ctx: ShardingCtx,
             tokens: Optional[jax.Array] = None,
             embeds: Optional[jax.Array] = None,
             want_cache: bool = False,
-            logits_positions: str = "all"):
+            logits_positions: str = "all",
+            codes: Optional[jax.Array] = None,
+            cond: Optional[jax.Array] = None,
+            cond_mask: Optional[jax.Array] = None):
     """Full-sequence forward.  Returns (logits, cache-or-None).
 
     ``tokens`` [b, s] for token frontends; ``embeds`` [b, s, e] for the
-    stubbed audio/vision frontends (precomputed frame/patch embeddings).
+    stubbed vision frontend (precomputed patch embeddings); ``codes``
+    [b, K, s] for codebook models, whose logits are [b, K, s, vocab].
+    Models with cross-attention take ``cond`` [b, t, cond_dim] and
+    ``cond_mask`` [b, t] (True on the real positions).
     ``logits_positions='last'`` (prefill serving) projects only the final
     position through the LM head — at 32K context this removes the
     [b, s, vocab] logits buffer entirely (§Perf).
     """
-    if embeds is not None:
+    if codes is not None:
+        b, _, s = codes.shape
+        x = embed_codes(codes, params["embed"], cfg, ctx)
+    elif embeds is not None:
         x = constrain(embeds.astype(jnp.dtype(cfg.dtype)), ctx,
                       "batch", "seq", "embed")
         b, s, _ = embeds.shape
@@ -115,6 +136,8 @@ def forward(params: Dict, cfg: ArchConfig, ctx: ShardingCtx,
     positions = make_positions(cfg, b, s)
     if cfg.rope == "abs_sin":
         x = x + _sinusoid(positions, cfg.d_model, x.dtype)
+    if cfg.cond_dim:
+        cond_x = project_cond(cond, params["cond"], cfg, ctx)
 
     groups, per = _group_layout(cfg)
     collect = want_cache
@@ -154,6 +177,10 @@ def forward(params: Dict, cfg: ArchConfig, ctx: ShardingCtx,
             x = constrain(x, ctx, "batch", "seq", "embed")
         else:
             x, kv_out = attn_block(x, bp["attn"])
+            if cfg.cond_dim:
+                x = x + cross_attention(x, bp["xattn"], cond_x, cond_mask,
+                                        cfg, ctx)
+                x = constrain(x, ctx, "batch", "seq", "embed")
             x = x + mlp(x, bp["mlp"], cfg, ctx)
             x = constrain(x, ctx, "batch", "seq", "embed")
         return x, kv_out
@@ -173,7 +200,10 @@ def forward(params: Dict, cfg: ArchConfig, ctx: ShardingCtx,
     x, caches = jax.lax.scan(step, x, blocks)
     if logits_positions == "last":
         x = x[:, -1:, :]
-    logits = lm_logits(x, params["embed"], cfg, ctx)
+    if cfg.is_codebook:
+        logits = codebook_logits(x, params["embed"], cfg, ctx)
+    else:
+        logits = lm_logits(x, params["embed"], cfg, ctx)
     return logits, (_pack_cache(cfg, caches) if want_cache else None)
 
 
@@ -197,6 +227,11 @@ def _pack_cache(cfg: ArchConfig, caches) -> Dict[str, jax.Array]:
 
 def loss_fn(params: Dict, cfg: ArchConfig, ctx: ShardingCtx,
             batch: Dict[str, jax.Array]) -> jax.Array:
+    if cfg.is_codebook:
+        logits, _ = forward(params, cfg, ctx, codes=batch["codes"],
+                            cond=batch.get("cond"),
+                            cond_mask=batch.get("cond_mask"))
+        return codebook_cross_entropy(logits, batch["labels"], cfg.vocab)
     logits, _ = forward(params, cfg, ctx,
                         tokens=batch.get("tokens"),
                         embeds=batch.get("embeds"))

@@ -20,9 +20,19 @@ for its whole life.  Elasticity events map as:
 
 Because growth and shrink ride the queue, training jobs and batch jobs
 share one lifecycle: the same events, the same accounting, the same
-preemption story.  The data plane is re-jitted against the new mesh;
-parameters/optimizer move via ``jax.device_put`` with the new
-NamedShardings (topology-independent layout keyed by logical axes).
+preemption story.  Parameters/optimizer move via ``jax.device_put`` with
+the new mesh's NamedShardings (topology-independent layout keyed by
+logical axes).  The runtime keeps one binding per mesh size (mesh,
+model, shardings, jitted step), built on the first bind to that size or
+ahead by :meth:`ElasticRuntime.prepare`: a resize to a size already
+seen builds nothing and, once that size has stepped, compiles nothing.
+
+With a ``SpanCollector`` on the scheduler (``span_collector``), a grow
+records ``elastic.grow`` (holding the queue's ``match_grow`` and the
+rebind), a shrink ``elastic.shrink``, and each rebind ``elastic.bind``
+with ``elastic.reshard`` (state on the new mesh, waited for) and, when a
+size is new, ``elastic.step_build``.  The counters ``n_resizes``,
+``n_step_builds`` and ``reshard_bytes`` are always on.
 """
 from __future__ import annotations
 
@@ -34,6 +44,7 @@ import jax
 
 from ..core.api import Instance, JobHandle
 from ..core.jobspec import Jobspec
+from ..core.metrics import NO_SPAN
 from ..core.scheduler import SchedulerInstance
 from ..models.config import ArchConfig, ShapeConfig
 from ..models.model import Model, make_model
@@ -48,6 +59,19 @@ class ElasticEvent:
     chips_before: int
     chips_after: int
     detail: str = ""
+
+
+@dataclass
+class _Binding:
+    """What one mesh size needs: the mesh, the model over it, the
+    training state's shardings, and the jitted init and train steps."""
+    mesh: Any
+    model: Model
+    param_sh: Any
+    opt_sh: Any
+    init_params: Any
+    init_opt: Any
+    train_step: Any
 
 
 class ElasticRuntime:
@@ -78,6 +102,14 @@ class ElasticRuntime:
         self._train_step = None
         self.params = None
         self.opt_state = None
+        self._bindings: Dict[int, _Binding] = {}
+        self.n_resizes = 0          # grows and shrinks that went through
+        self.n_step_builds = 0      # bindings built (one per mesh size)
+        self.reshard_bytes = 0      # training state moved between meshes
+
+    def _span(self, name: str, **attrs):
+        col = self.scheduler.span_collector
+        return NO_SPAN if col is None else col.span(name, **attrs)
 
     # ---------------------------------------------------------------- #
     def chips_allocated(self) -> int:
@@ -101,36 +133,64 @@ class ElasticRuntime:
         return max(usable, self.model_axis)
 
     # ---------------------------------------------------------------- #
-    def bind(self, key: Optional[jax.Array] = None) -> None:
-        """(Re)build mesh + model + jitted step for current allocation,
-        re-sharding existing state (or initializing it with ``key``)."""
+    def prepare(self, sizes) -> None:
+        """Build the binding of each mesh size in ``sizes`` ahead of the
+        resizes that will use it (a step compiles on its first call)."""
+        for n in sizes:
+            self._binding(n)
+
+    def _binding(self, n: int) -> _Binding:
+        b = self._bindings.get(n)
+        if b is not None:
+            return b
         from ..launch.mesh import make_mesh_for
+        with self._span("elastic.step_build", devices=n):
+            mesh = make_mesh_for(n, self.model_axis)
+            model = make_model(self.cfg, ShardingCtx(self.rules, mesh),
+                               self.opt)
+            psh, osh = model.param_shardings(), model.opt_shardings()
+            b = self._bindings[n] = _Binding(
+                mesh, model, psh, osh,
+                jax.jit(model.init_params, out_shardings=psh),
+                jax.jit(model.init_opt, out_shardings=osh),
+                jax.jit(model.train_step, out_shardings=(psh, osh, None),
+                        donate_argnums=(0, 1)))
+        self.n_step_builds += 1
+        return b
+
+    def bind(self, key: Optional[jax.Array] = None) -> None:
+        """Bind to the mesh of the current allocation, re-sharding the
+        existing state onto it (or initializing it with ``key``)."""
         n = self._usable_devices()
         before = 0 if self.mesh is None else self.mesh.size
-        self.mesh = make_mesh_for(n, self.model_axis)
-        ctx = ShardingCtx(self.rules, self.mesh)
-        self.model = make_model(self.cfg, ctx, self.opt)
-        psh = self.model.param_shardings()
-        osh = self.model.opt_shardings()
-        if self.params is None:
-            if key is None:
-                key = jax.random.key(0)
-            with self.mesh:
-                self.params = jax.jit(
-                    self.model.init_params, out_shardings=psh)(key)
-                self.opt_state = jax.jit(
-                    self.model.init_opt, out_shardings=osh)(self.params)
-        else:
-            # re-shard existing state onto the new mesh (elastic move)
-            self.params = jax.device_put(self.params, psh)
-            self.opt_state = jax.device_put(self.opt_state, osh)
-        self._train_step = jax.jit(
-            self.model.train_step,
-            out_shardings=(psh, osh, None),
-            donate_argnums=(0, 1))
+        with self._span("elastic.bind", devices=n):
+            b = self._binding(n)
+            self.mesh, self.model, self._train_step = \
+                b.mesh, b.model, b.train_step
+            if self.params is None:
+                self.init_state(key)
+            else:
+                # re-shard existing state onto the new mesh (elastic move)
+                with self._span("elastic.reshard"):
+                    moved = sum(x.nbytes for x in jax.tree_util.tree_leaves(
+                        (self.params, self.opt_state)))
+                    self.params = jax.device_put(self.params, b.param_sh)
+                    self.opt_state = jax.device_put(self.opt_state, b.opt_sh)
+                    jax.block_until_ready((self.params, self.opt_state))
+                self.reshard_bytes += moved
         self.events.append(ElasticEvent(
             "rebind", time.time(), before, self.mesh.size,
             f"devices={self.mesh.size} model_axis={self.model_axis}"))
+
+    def init_state(self, key: Optional[jax.Array] = None) -> None:
+        """(Re)initialize parameters and optimizer state on the bound
+        mesh from ``key`` (default ``jax.random.key(0)``)."""
+        b = self._bindings[self.mesh.size]
+        if key is None:
+            key = jax.random.key(0)
+        with self.mesh:
+            self.params = b.init_params(key)
+            self.opt_state = b.init_opt(self.params)
 
     # ---------------------------------------------------------------- #
     def allocate(self, chips: int) -> bool:
@@ -156,12 +216,14 @@ class ElasticRuntime:
             return False
         before = self.chips_allocated()
         js = Jobspec(resources=[ResourceReq(self.chip_type, chips)])
-        if not self.handle.grow(js):
-            return False
-        self.events.append(ElasticEvent(
-            "grow", time.time(), before, self.chips_allocated(),
-            f"+{chips} {self.chip_type}"))
-        self.bind()
+        with self._span("elastic.grow", chips=chips):
+            if not self.handle.grow(js):
+                return False
+            self.events.append(ElasticEvent(
+                "grow", time.time(), before, self.chips_allocated(),
+                f"+{chips} {self.chip_type}"))
+            self.bind()
+        self.n_resizes += 1
         return True
 
     def shrink(self, chips: int) -> bool:
@@ -179,12 +241,14 @@ class ElasticRuntime:
         if len(victims) - chips < self.model_axis:
             return False
         before = self.chips_allocated()
-        if not self.handle.shrink(paths=victims[-chips:]):
-            return False
-        self.events.append(ElasticEvent(
-            "shrink", time.time(), before, self.chips_allocated(),
-            f"-{chips} {self.chip_type}"))
-        self.bind()
+        with self._span("elastic.shrink", chips=chips):
+            if not self.handle.shrink(paths=victims[-chips:]):
+                return False
+            self.events.append(ElasticEvent(
+                "shrink", time.time(), before, self.chips_allocated(),
+                f"-{chips} {self.chip_type}"))
+            self.bind()
+        self.n_resizes += 1
         return True
 
     # ---------------------------------------------------------------- #

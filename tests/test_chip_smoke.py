@@ -49,6 +49,7 @@ def test_elastic_phase_toy(smoke):
         ["reference", "start", "grow +2", "shrink -2"]
     assert res["events"] == ["rebind", "grow", "rebind", "shrink", "rebind"]
     assert len(res["losses"]) == 6
+    assert res["inputs"] == ["codes", "cond", "cond_mask", "labels"]
 
 
 def test_main_refuses_cpu(smoke, capsys):

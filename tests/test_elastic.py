@@ -41,6 +41,7 @@ def test_shrink_keeps_queue_and_scheduler_accounting_in_agreement():
     rt.chip_type = "chip"
     rt.model_axis = 1
     rt.events = []
+    rt.n_resizes = 0
 
     def agree():
         job = api.queue.get(rt.jobid)
@@ -195,3 +196,54 @@ assert err2 <= float(s.max()) + 1e-6
 print("COMPRESS_OK", err)
 """, devices=4)
     assert "COMPRESS_OK" in out
+
+
+def test_elastic_spans_and_counters():
+    """With a collector on the scheduler, a grow records ``elastic.grow``
+    holding the queue's ``match_grow`` and the rebind (``elastic.bind``
+    > ``elastic.reshard``), a shrink ``elastic.shrink``; a size already
+    bound builds no step.  Detached, nothing is recorded; the counters
+    count every resize either way (one host device: every mesh is 1)."""
+    import jax
+
+    from repro.configs.registry import get_config
+    from repro.core import Instance, SchedulerInstance, SpanCollector
+    from repro.core.graph import build_tpu_fleet
+    from repro.models.config import ShapeConfig
+    from repro.runtime.elastic import ElasticRuntime
+
+    fleet = build_tpu_fleet(pods=1, racks_per_pod=1, nodes_per_rack=1,
+                            chips_per_node=4)
+    rt = ElasticRuntime(Instance(SchedulerInstance("host", fleet)),
+                        get_config("musicgen-medium").reduced(),
+                        ShapeConfig("t", 16, 2, "train", cond_len=8),
+                        chip_type="chip")
+    assert rt.allocate(2)
+    rt.bind(jax.random.key(0))
+    col = SpanCollector()
+    assert rt.grow(2) and rt.shrink(2)
+    assert col.recorded == 0 and rt.n_resizes == 2
+
+    rt.scheduler.span_collector = col
+    assert rt.grow(2) and rt.shrink(2)
+    spans = col.drain()
+    by_id = {s["id"]: s for s in spans}
+
+    def parent(s):
+        return by_id[s["parent"]]["name"] if s["parent"] else None
+    names = [s["name"] for s in spans]
+    assert names.count("elastic.grow") == names.count("elastic.shrink") == 1
+    assert "elastic.step_build" not in names
+    for s in spans:
+        if s["name"] == "match_grow":
+            assert parent(s) == "elastic.grow"
+        if s["name"] == "elastic.bind":
+            assert parent(s) in ("elastic.grow", "elastic.shrink")
+        if s["name"] == "elastic.reshard":
+            assert parent(s) == "elastic.bind"
+    assert names.count("match_grow") == 1
+    assert names.count("elastic.bind") == names.count("elastic.reshard") == 2
+    assert rt.n_resizes == 4 and rt.n_step_builds == 1
+    nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(
+        (rt.params, rt.opt_state)))
+    assert rt.reshard_bytes == 4 * nbytes

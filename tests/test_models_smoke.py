@@ -12,6 +12,11 @@ from repro.models.model import make_model
 
 
 def _batches(cfg, b=2, s=32):
+    if cfg.is_codebook:
+        from repro.data.pipeline import SyntheticTokenPipeline
+        train = SyntheticTokenPipeline(
+            cfg, ShapeConfig("t", s, b, "train", cond_len=8)).batch_at(0)
+        return {k: jnp.asarray(v) for k, v in train.items()}, None
     stub = cfg.frontend != "token"
     if stub:
         train = {"embeds": jnp.ones((b, s, cfg.d_model), jnp.float32),
@@ -31,7 +36,7 @@ def test_arch_smoke(arch_id, rng_key):
     params = model.init_params(rng_key)
     opt = model.init_opt(params)
     train, dec = _batches(cfg)
-    b, s = train["labels"].shape
+    b, s = train["labels"].shape[0], train["labels"].shape[-1]
 
     p2, o2, metrics = jax.jit(model.train_step)(params, opt, train)
     assert np.isfinite(float(metrics["loss"]))
@@ -41,6 +46,12 @@ def test_arch_smoke(arch_id, rng_key):
     assert not np.allclose(np.asarray(l0), np.asarray(l1))
 
     prompt = {k: v for k, v in train.items() if k != "labels"}
+    if cfg.is_codebook:     # trains only: serving needs a cross-attn cache
+        with pytest.raises(NotImplementedError, match="codebook"):
+            model.prefill_step(params, prompt)
+        with pytest.raises(NotImplementedError, match="codebook"):
+            model.serve_step(params, {}, prompt, jnp.int32(0))
+        return
     logits, cache = jax.jit(model.prefill_step)(params, prompt)
     assert logits.shape == (b, 1, cfg.vocab)
     lg, cache2 = jax.jit(model.serve_step)(params, cache, dec,
