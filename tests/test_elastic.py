@@ -78,6 +78,65 @@ def _run(code: str, devices: int = 8, timeout: int = 600) -> str:
     return out.stdout
 
 
+RESHARD = """
+import jax, numpy as np
+from jax._src import array
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh_for
+from repro.runtime.elastic import reshard
+
+spec, model_axis = P(*{spec!r}), {model_axis}
+fetches, compiles = [], []
+value = array.ArrayImpl._value
+array.ArrayImpl._value = property(
+    lambda self: fetches.append(1) or value.fget(self))
+jax.monitoring.register_event_duration_secs_listener(
+    lambda ev, d, **_: compiles.append(ev)
+    if ev == "/jax/core/compile/backend_compile_duration" else None)
+
+want = {{"w": np.arange(8 * 12 * 3, dtype=np.float32).reshape(8, 12, 3),
+        "b": np.arange(8, dtype=np.int32), "step": np.int32(7)}}
+specs = {{"w": spec, "b": P(*spec[:1]), "step": P()}}
+
+def on(mesh):
+    return {{k: NamedSharding(mesh, s) for k, s in specs.items()}}
+
+small, big = make_mesh_for(2, model_axis), make_mesh_for(4, model_axis)
+state = jax.device_put(want, on(small))
+moves = {{}}
+for rnd in range(2):
+    n0 = len(compiles)
+    for mesh in (big, small):
+        f0 = len(fetches)
+        state, copied, fallback = reshard(state, on(mesh), moves)
+        assert len(fetches) == f0, "staged through the host"
+        assert fallback == 0 and (copied > 0 or mesh is small), copied
+        for k, x in state.items():
+            assert x.sharding == on(mesh)[k], (k, x.sharding)
+            assert x.devices() == set(mesh.devices.flat), (k, x.devices())
+            assert np.asarray(x).tobytes() == want[k].tobytes(), k
+    if rnd:
+        assert len(compiles) == n0, "a warm move compiled"
+
+# a move onto equivalent shardings copies nothing
+same, copied, fallback = reshard(state, on(small), moves)
+assert copied == fallback == 0 and same["w"].sharding == on(small)["w"]
+print("RESHARD_OK")
+"""
+
+
+@pytest.mark.parametrize("spec,model_axis", [
+    (("data",), 1), ((None, "data"), 1), ((), 1), (("data", "model"), 2)])
+def test_reshard_moves_shard_to_shard(spec, model_axis):
+    """A 2 -> 4 -> 2 device move of the training state's layouts goes
+    shard to shard: bit-exact values, the target sharding on exactly the
+    target mesh's devices, no host fetch (``ArrayImpl._value``), nothing
+    through the ``device_put`` fallback, and a second grow and shrink
+    compile nothing."""
+    out = _run(RESHARD.format(spec=spec, model_axis=model_axis), devices=4)
+    assert "RESHARD_OK" in out
+
+
 @pytest.mark.slow
 def test_grow_shrink_fail_loop(tmp_path):
     out = _run(f"""
@@ -247,3 +306,9 @@ def test_elastic_spans_and_counters():
     nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(
         (rt.params, rt.opt_state)))
     assert rt.reshard_bytes == 4 * nbytes
+    # one device: the shardings are equivalent, nothing changes chips
+    assert rt.reshard_fallback_bytes == 0
+    for s in spans:
+        if s["name"] == "elastic.reshard":
+            assert (s["bytes"], s["copied_bytes"], s["fallback_bytes"]) \
+                == (nbytes, 0, 0)

@@ -254,6 +254,7 @@ assert warm == 0, warm
 nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(
     (rt.params, rt.opt_state)))
 assert rt.reshard_bytes == 4 * nbytes
+assert rt.reshard_fallback_bytes == 0
 
 bad = runtime(2)
 bad.reset_moments = True
