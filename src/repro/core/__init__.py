@@ -1,8 +1,8 @@
 """Paper contribution: dynamic, hierarchical graph-based resource model."""
 from .graph import CONTAINMENT, ResourceGraph, Vertex, build_cluster, build_tpu_fleet
 from .jobspec import Jobspec, ResourceReq
-from .match import Matcher
-from .flatgraph import FlatGraph, FlatMatcher, flat_enabled
+from .match import DictMatcher, Matcher
+from .flatgraph import FlatGraph, FlatMatcher, flat_for
 from .actor import ActorGroup, QueueActor, check_actor_safe
 from .transform import (TransformKind, TransformResult, add_subgraph,
                         remove_subgraph, update_metadata)
@@ -30,7 +30,7 @@ from .rpc import (ClientReactor, MethodRegistry, MuxServer, MuxTransport,
 __all__ = [
     "CONTAINMENT", "ResourceGraph", "Vertex", "build_cluster",
     "build_tpu_fleet", "Jobspec", "ResourceReq", "Matcher",
-    "FlatGraph", "FlatMatcher", "flat_enabled",
+    "DictMatcher", "FlatGraph", "FlatMatcher", "flat_for",
     "ActorGroup", "QueueActor", "check_actor_safe", "TransformKind",
     "TransformResult", "add_subgraph", "remove_subgraph", "update_metadata",
     "Allocation", "GrowEngine", "GrowResult", "Hierarchy", "MGTiming",

@@ -23,21 +23,23 @@ trial.  This module keeps a contiguous mirror of the same state —
   subtrees — and failure ("nothing can match") is detected without
   entering the graph at all.
 
-The per-level aggregate sweep dispatches like the Pallas kernel
-wrappers in ``src/repro/kernels/ops.py``: ``use_jax='auto'`` selects a
-``jax.jit`` segment-sum scan on accelerator backends and plain numpy
-elsewhere; ``'jax'`` / ``'numpy'`` force a path.
+Which path runs is decided in two places, both in this module, from
+what the code can observe.  :func:`on_device` is the platform: on a
+TPU backend the aggregate sweep is one jitted segment-sum scan and the
+batched scan is the ``kernels/feasibility.py`` Pallas kernel; numpy
+runs them everywhere else.  :func:`flat_for` is the graph's size: the
+flat mirror is kept from ``FLAT_MIN_VERTICES`` vertices up, and the
+matcher also weighs the request (``FLAT_REQ_RATIO``).
 
 :class:`FlatMatcher` is a faithful port of the DFS in ``core/match.py``
 to integer indices (same traversal order, same claim/rollback
 semantics, via an undo journal instead of per-trial set copies), so the
 flat and dict matchers return **identical** matches; the dict matcher
-remains as the oracle (``Matcher(g, use_flat=False)``).
+(``match.DictMatcher``) remains as the oracle.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -45,23 +47,18 @@ import numpy as np
 from .jobspec import Jobspec, ResourceReq
 from .metrics import NO_SPAN
 
-# vertices below this count: vectorized prefilters cost more than the
-# plain int-DFS saves, so FlatMatcher skips them (the arrays are still
-# what makes the DFS itself fast)
-VECTOR_MIN_VERTICES = 192
-
 # graphs below this count: the flat path's fixed per-match cost (sync,
 # request compilation, column snapshots) exceeds what the dict DFS
-# spends on the whole match, so ``Matcher`` keeps the dict path.  The
-# measured crossover on build_cluster shapes is ~500 vertices.
+# spends on the whole match, so they keep no mirror and match on the
+# dict graph.  The measured crossover on build_cluster shapes is ~500
+# vertices.
 FLAT_MIN_VERTICES = 512
 
-# on the auto path, requests smaller than |V| / FLAT_REQ_RATIO also
-# stay on the dict DFS: a small request on a big graph descends
-# straight down the pruned spine in ~10us, well under the flat path's
-# O(|V|) per-match column snapshots (~0.8ms at 2k vertices), while the
-# dict DFS's per-trial set copies grow superlinearly with request
-# size.  Measured crossovers: request ~400 at 2241 vertices, ~700-900
+# requests smaller than |V| / FLAT_REQ_RATIO also match on the dict
+# DFS: a small request on a big graph descends straight down the pruned
+# spine in ~10us, well under the flat path's O(|V|) per-match column
+# snapshots (~0.8ms at 2k vertices), while the dict DFS's per-trial set
+# copies grow superlinearly with request size.  Measured crossovers: request ~400 at 2241 vertices, ~700-900
 # at 4481 — i.e. request ~ |V| / 6.
 FLAT_REQ_RATIO = 6
 
@@ -69,34 +66,55 @@ _NO_PROPS: Dict[str, str] = {}
 
 
 # ---------------------------------------------------------------------- #
-# vectorized per-level aggregate sweep (numpy / jax.jit dispatch)
+# the two path decisions
 # ---------------------------------------------------------------------- #
-def _on_accelerator() -> bool:
-    """True when JAX's default backend is not the CPU: the platform
-    gate of every ``use_jax='auto'`` dispatch in this module."""
+@functools.lru_cache(maxsize=None)
+def on_device() -> bool:
+    """True on a TPU backend: the aggregate sweep and the batched scan
+    run on the chip there, numpy runs them everywhere else.  Asked on
+    first use (so importing this module never imports jax), then
+    cached."""
     import jax
-    return jax.default_backend() != "cpu"
+    return jax.default_backend() == "tpu"
 
 
+def flat_for(graph, jobspec: Optional[Jobspec] = None
+             ) -> Optional["FlatGraph"]:
+    """The graph's flat mirror, built on first use, when one is already
+    kept or the graph has ``FLAT_MIN_VERTICES`` vertices or more; else
+    None (the dict graph serves).  Given the ``jobspec`` to match, also
+    None when the request is under |V| / ``FLAT_REQ_RATIO``."""
+    n = graph.num_vertices
+    if graph._flat is None and n < FLAT_MIN_VERTICES:
+        return None
+    if jobspec is not None and jobspec.graph_size() * FLAT_REQ_RATIO < n:
+        return None
+    return graph.flat()
+
+
+# ---------------------------------------------------------------------- #
+# vectorized per-level aggregate sweep (numpy, or jitted on the device)
+# ---------------------------------------------------------------------- #
 def aggregate_sweep(own: np.ndarray, parent: np.ndarray,
-                    levels: Sequence[np.ndarray],
-                    use_jax: str = "auto") -> np.ndarray:
+                    levels: Sequence[np.ndarray]) -> np.ndarray:
     """Bottom-up subtree-sum over a forest, one tree level at a time.
 
     ``own[v, t]`` is vertex ``v``'s own contribution per type;
     ``levels`` lists vertex indices grouped by depth, root level first.
-    Returns ``agg`` with ``agg[v] = sum(own[u] for u in subtree(v))``.
-
-    ``use_jax='auto'`` follows the ``kernels/ops.py`` idiom: the jitted
-    scan runs on accelerator backends, numpy everywhere else.
+    Returns ``agg`` with ``agg[v] = sum(own[u] for u in subtree(v))``:
+    the jitted scan when :func:`on_device`, numpy otherwise.
     """
-    if use_jax == "numpy" or (use_jax == "auto" and not _on_accelerator()):
-        agg = own.copy()
-        for lvl in reversed(levels[1:]):        # deepest first
-            par = parent[lvl]
-            np.add.at(agg, par, agg[lvl])
-        return agg
-    return _aggregate_sweep_jax(own, parent, levels)
+    if on_device():
+        return _aggregate_sweep_jax(own, parent, levels)
+    return _aggregate_sweep_numpy(own, parent, levels)
+
+
+def _aggregate_sweep_numpy(own: np.ndarray, parent: np.ndarray,
+                           levels: Sequence[np.ndarray]) -> np.ndarray:
+    agg = own.copy()
+    for lvl in reversed(levels[1:]):        # deepest first
+        np.add.at(agg, parent[lvl], agg[lvl])
+    return agg
 
 
 @functools.lru_cache(maxsize=None)
@@ -144,20 +162,21 @@ def candidate_mask(type_id: np.ndarray, free: np.ndarray,
     return m
 
 
-def batched_candidate_mask(type_id: np.ndarray, free: np.ndarray,
-                           present: np.ndarray, size: np.ndarray,
-                           prop_mask: np.ndarray, agg: np.ndarray,
-                           tid: np.ndarray, min_size: np.ndarray,
-                           req_mask: np.ndarray,
+def batched_candidate_mask(type_id: np.ndarray, ok: np.ndarray,
+                           size: np.ndarray, prop_mask: np.ndarray,
+                           agg: np.ndarray, tid: np.ndarray,
+                           min_size: np.ndarray, req_mask: np.ndarray,
                            need: np.ndarray) -> np.ndarray:
-    """:func:`candidate_mask` for a whole *request matrix* at once.
+    """:func:`candidate_mask` for a whole *request matrix* at once: the
+    host twin of ``kernels/feasibility``'s kernel, on the same operands
+    (:meth:`FlatGraph.scan_operands`).
 
-    ``tid`` / ``min_size`` / ``req_mask`` are ``[N]`` per-request
-    columns and ``need`` is the dense ``[N, T]`` per-type aggregate
-    requirement; the result is the ``[N, V]`` feasibility mask — one
-    vectorized pass over the pruning table instead of N scans."""
-    base = free & present
-    m = (type_id[None, :] == tid[:, None]) & base[None, :]
+    ``ok`` is free-and-present per vertex; ``tid`` / ``min_size`` /
+    ``req_mask`` are ``[N]`` per-request columns and ``need`` is the
+    dense ``[N, T]`` per-type aggregate requirement; the result is the
+    ``[N, V]`` feasibility mask — one vectorized pass over the pruning
+    table instead of N scans."""
+    m = (type_id[None, :] == tid[:, None]) & ok[None, :]
     m &= size[None, :] >= min_size[:, None]
     rm = req_mask[:, None]
     m &= (prop_mask[None, :] & rm) == rm
@@ -388,7 +407,7 @@ class FlatGraph:
         self._synced_version = -1
 
     # -- settling ------------------------------------------------------- #
-    def sync(self, use_jax: str = "auto") -> None:
+    def sync(self) -> None:
         """Settle queued dirty state.  Alloc/release flips bubble their
         deltas up the ancestor chains (vectorized, never a rebuild);
         topology changes run one vectorized per-level sweep.
@@ -402,15 +421,15 @@ class FlatGraph:
             return
         col = self.g.span_collector
         if col is None:
-            self._settle(use_jax)
+            self._settle()
             return
         with col.span("flat.sync", struct=self._struct_dirty):
-            self._settle(use_jax)
+            self._settle()
 
-    def _settle(self, use_jax: str) -> None:
+    def _settle(self) -> None:
         if self._struct_dirty:
             self._refresh_levels()
-            self._sweep(use_jax)
+            self._sweep()
             self._pending.clear()
             self._struct_dirty = False
         elif self._pending:
@@ -447,15 +466,14 @@ class FlatGraph:
         own[live, self.type_id[live]] = 1
         return own
 
-    def _sweep(self, use_jax: str = "auto") -> None:
+    def _sweep(self) -> None:
         self.n_agg_sweeps += 1
         n, T = self.n, len(self.types)
         own = self.own_counts()
         if self._levels:
             col = self.g.span_collector
             with NO_SPAN if col is None else col.span("flat.sweep"):
-                agg = aggregate_sweep(own, self.parent[:n], self._levels,
-                                      use_jax=use_jax)
+                agg = aggregate_sweep(own, self.parent[:n], self._levels)
         else:
             agg = own
         self.agg[:n, :T] = agg
@@ -503,12 +521,11 @@ class FlatGraph:
         self._req_cache[key] = (req, gen, c)
         return c
 
-    def feasible_roots(self, req: ResourceReq,
-                       use_jax: str = "auto") -> np.ndarray:
+    def feasible_roots(self, req: ResourceReq) -> np.ndarray:
         """Indices of vertices where a match of ``req`` could root
         (vectorized necessary-condition scan).  Empty array == the
         request provably cannot match anywhere."""
-        self.sync(use_jax)
+        self.sync()
         c = self.compiled(req)
         if c.tid is None:
             return np.empty(0, np.int64)
@@ -519,8 +536,8 @@ class FlatGraph:
                               c.tid, c.min_size, c.req_mask, c.agg_need)
         return np.nonzero(mask)[0]
 
-    def feasible_roots_batch(self, reqs: Sequence[ResourceReq],
-                             use_jax: str = "auto") -> np.ndarray:
+    def feasible_roots_batch(self, reqs: Sequence[ResourceReq]
+                             ) -> np.ndarray:
         """``feasible_roots`` for N requests in **one** vectorized pass.
 
         The compiled requests are stacked into a request matrix and
@@ -529,11 +546,9 @@ class FlatGraph:
         (``mask[i].nonzero()`` == ``feasible_roots(reqs[i])``).  A
         backfill window repeats a handful of request shapes, so rows
         are deduplicated by compiled signature first — the scan cost is
-        one pass over the *unique* shapes, not over N.
-
-        Dispatch follows :func:`aggregate_sweep`: numpy on CPU
-        backends, the ``kernels/feasibility.py`` jax/Pallas variant on
-        accelerators (``use_jax='jax'`` forces it).
+        one pass over the *unique* shapes, not over N.  The pass is the
+        ``kernels/feasibility.py`` kernel when :func:`on_device`,
+        :func:`batched_candidate_mask` otherwise.
 
         With a span collector on the graph, a call is a ``flat.scan``
         span holding ``scan.prep`` (signature dedupe, the request
@@ -542,13 +557,41 @@ class FlatGraph:
         host)."""
         col = self.g.span_collector
         if col is None:
-            return self._scan(reqs, use_jax, None)
+            return self._scan(reqs, None)
         with col.span("flat.scan", rows=len(reqs)):
-            return self._scan(reqs, use_jax, col)
+            return self._scan(reqs, col)
 
-    def _scan(self, reqs: Sequence[ResourceReq], use_jax: str,
-              col) -> np.ndarray:
-        self.sync(use_jax)
+    def scan_operands(self, reqs: Sequence[ResourceReq]
+                      ) -> Tuple[Dict[Tuple, List[int]], tuple]:
+        """The batched scan's input on the mirror as last synced: the
+        rows of ``reqs`` grouped by compiled signature (a request whose
+        type is absent has none), and the operands both scan halves
+        take, one request row per signature in that order: the vertex
+        columns ``type_id``, free-and-present, ``size``, ``prop_mask``
+        and ``agg``, then ``tid``, ``min_size``, ``req_mask`` and
+        ``need``."""
+        sig_rows: Dict[Tuple, List[int]] = {}
+        for i, req in enumerate(reqs):
+            c = self.compiled(req)
+            if c.tid is None:   # some required type absent: no row
+                continue
+            sig = (c.tid, c.min_size, c.req_mask, tuple(c.agg_need))
+            sig_rows.setdefault(sig, []).append(i)
+        n, U, T = self.n, len(sig_rows), len(self.types)
+        tid = np.fromiter((s[0] for s in sig_rows), np.int32, U)
+        min_size = np.fromiter((s[1] for s in sig_rows), np.int32, U)
+        req_mask = np.fromiter((s[2] for s in sig_rows), np.int64, U)
+        need = np.zeros((U, T), np.int32)
+        for u, s in enumerate(sig_rows):
+            for t, k in s[3]:
+                need[u, t] = k
+        ok = self.free[:n] & self.present[:n]
+        return sig_rows, (self.type_id[:n], ok, self.size[:n],
+                          self.prop_mask[:n], self.agg[:n, :T],
+                          tid, min_size, req_mask, need)
+
+    def _scan(self, reqs: Sequence[ResourceReq], col) -> np.ndarray:
+        self.sync()
         n, N = self.n, len(reqs)
         self.n_scans += 1
         self.n_scan_rows += N
@@ -556,48 +599,25 @@ class FlatGraph:
         if N == 0 or n == 0:
             return out
         with NO_SPAN if col is None else col.span("scan.prep"):
-            sig_rows: Dict[Tuple, List[int]] = {}
-            for i, req in enumerate(reqs):
-                c = self.compiled(req)
-                if c.tid is None:   # some required type absent: no row
-                    continue
-                sig = (c.tid, c.min_size, c.req_mask, tuple(c.agg_need))
-                sig_rows.setdefault(sig, []).append(i)
+            sig_rows, ops = self.scan_operands(reqs)
             if not sig_rows:
                 return out
-            uniq = list(sig_rows)
-            U, T = len(uniq), len(self.types)
-            tid = np.fromiter((s[0] for s in uniq), np.int32, U)
-            min_size = np.fromiter((s[1] for s in uniq), np.int32, U)
-            req_mask = np.fromiter((s[2] for s in uniq), np.int64, U)
-            need = np.zeros((U, T), np.int32)
-            for u, s in enumerate(uniq):
-                for t, k in s[3]:
-                    need[u, t] = k
-            on_device = use_jax != "numpy" and (use_jax != "auto"
-                                                or _on_accelerator())
-            if on_device:
+            device = on_device()
+            if device:
                 from ..kernels.feasibility import pack_inputs, run_packed
-                path, args = pack_inputs(
-                    self.type_id[:n], (self.free[:n] & self.present[:n]),
-                    self.size[:n], self.prop_mask[:n], self.agg[:n, :T],
-                    tid, min_size, req_mask, need)
+                args = pack_inputs(*ops)
+        U = len(sig_rows)
         self.n_scan_unique += U
-        if on_device:
+        if device:
             with NO_SPAN if col is None else col.span("scan.device"):
-                full = run_packed(path, args)
+                full = run_packed(args)
             self.scan_h2d_bytes += sum(a.nbytes for a in args)
             self.scan_d2h_bytes += full.nbytes
             m = full[:U, :n] != 0
         else:
-            m = batched_candidate_mask(
-                self.type_id[:n], self.free[:n], self.present[:n],
-                self.size[:n], self.prop_mask[:n], self.agg[:n, :T],
-                tid, min_size, req_mask, need)
-        for u, s in enumerate(uniq):
-            row = m[u]
-            for i in sig_rows[s]:
-                out[i] = row
+            m = batched_candidate_mask(*ops)
+        for row, rows in zip(m, sig_rows.values()):
+            out[rows] = row
         return out
 
     # -- verification (tests) ------------------------------------------- #
@@ -684,14 +704,13 @@ class FlatMatcher:
     returns exactly what the dict matcher returns, faster.
     """
 
-    def __init__(self, flat: FlatGraph, use_jax: str = "auto"):
+    def __init__(self, flat: FlatGraph):
         self.f = flat
-        self.use_jax = use_jax
         self.visited = 0
 
     def match(self, jobspec: Jobspec) -> Optional[List[str]]:
         f = self.f
-        f.sync(self.use_jax)
+        f.sync()
         self.visited = 0
         n = f.n
         claimed = bytearray(n)
@@ -721,22 +740,18 @@ class FlatMatcher:
         return [path[i] for i in matched]
 
     # -- vectorized prefilter ------------------------------------------ #
-    def _cand_counts(self, c: _CompiledReq) -> Optional[List[int]]:
+    def _cand_counts(self, c: _CompiledReq) -> List[int]:
         """Per-vertex count of feasible candidate roots for ``c`` in
-        the subtree — the prefilter the DFS prunes on.  None when the
-        graph is too small for vectorization to pay off."""
+        the subtree — the prefilter the DFS prunes on."""
         f = self.f
         n = f.n
-        if n < VECTOR_MIN_VERTICES:
-            return None
         mask = candidate_mask(f.type_id[:n], f.free[:n], f.present[:n],
                               f.size[:n], f.prop_mask[:n], f.agg[:n],
                               c.tid, c.min_size, c.req_mask, c.agg_need)
         own = mask.astype(np.int32)[:, None]
         col = f.g.span_collector
         with NO_SPAN if col is None else col.span("flat.sweep"):
-            agg = aggregate_sweep(own, f.parent[:n], f._levels,
-                                  use_jax=self.use_jax)
+            agg = aggregate_sweep(own, f.parent[:n], f._levels)
         return agg[:, 0].tolist()
 
     def _agg(self, tid: int) -> List[int]:
@@ -778,23 +793,19 @@ class FlatMatcher:
 
     def _match_count(self, scope: int, c: _CompiledReq,
                      claimed: bytearray, undo: List[int],
-                     cand_in: Optional[List[int]]) -> Optional[List[int]]:
+                     cand_in: List[int]) -> Optional[List[int]]:
         got: List[int] = []
         mark = len(undo)
         need = c.count
         children = self._children
-        agg_t = self._agg(c.tid)
         stack = [scope]
         while stack and need > 0:
             i = stack.pop()
             if claimed[i]:
                 continue
             self.visited += 1
-            if cand_in is not None:
-                if cand_in[i] == 0:
-                    continue        # no feasible candidate below at all
-            elif agg_t[i] < 1:
-                continue            # classic pruning-filter skip
+            if cand_in[i] == 0:
+                continue            # no feasible candidate below at all
             if self._satisfies(i, c) and self._feasible_here(i, c):
                 sub = self._match_one(i, c, claimed, undo)
                 if sub is not None:
@@ -849,9 +860,3 @@ class FlatMatcher:
             return None
         return got
 
-
-def flat_enabled() -> bool:
-    """Module-level default for the flat fast path; the
-    ``CONVERGED_FLAT_MATCH`` env var ('0' disables) is the escape
-    hatch benchmarks use to measure the dict path."""
-    return os.environ.get("CONVERGED_FLAT_MATCH", "1") != "0"

@@ -6,40 +6,32 @@ per-vertex subtree free-count aggregates maintained by ``ResourceGraph``
 never entered if it cannot possibly satisfy the remaining request, so
 allocated subtrees are skipped (paper Section 5.2.3).
 
-By default matching runs on the graph's flat-array mirror
-(``core/flatgraph.FlatMatcher``) — same traversal, same claims, same
-result, via contiguous arrays and a vectorized feasibility prefilter.
-The dict DFS below remains the oracle: ``Matcher(g, use_flat=False)``
-(or env ``CONVERGED_FLAT_MATCH=0``) forces it, and the tier-1 suite
-asserts both return identical matches.
+The scheduler calls :class:`Matcher`, which picks per request, through
+``flatgraph.flat_for``: the graph's flat-array mirror
+(``core/flatgraph.FlatMatcher``, the same traversal, claims and result
+via contiguous arrays and a vectorized feasibility prefilter) for a
+large graph and request, :class:`DictMatcher` below otherwise.  The
+dict DFS is also the oracle: the tier-1 suite asserts that both return
+identical matches.
 """
 from __future__ import annotations
 
 from typing import List, Optional, Set
 
+from .flatgraph import FlatMatcher, flat_for
 from .graph import ResourceGraph, Vertex
 from .jobspec import Jobspec, ResourceReq
 
 
 class Matcher:
-    """DFS matcher over a ResourceGraph."""
+    """The scheduler's matcher: :class:`FlatMatcher` on the flat mirror
+    where ``flat_for`` returns one for the request, :class:`DictMatcher`
+    otherwise.  The choice is made per match, so a graph that grows
+    past the cutoff switches over."""
 
-    def __init__(self, graph: ResourceGraph,
-                 use_flat: Optional[bool] = None):
+    def __init__(self, graph: ResourceGraph):
         self.g = graph
-        # visit statistics, useful for verifying pruning behaviour
-        self.visited = 0
-        self._auto = use_flat is None
-        if use_flat is None:
-            from .flatgraph import FLAT_MIN_VERTICES, flat_enabled
-            # small graphs match faster through the dict DFS than the
-            # flat path's fixed per-match setup; the cutoff re-evaluates
-            # per Matcher, so a graph that grows past it switches over
-            use_flat = (flat_enabled()
-                        and graph.num_vertices >= FLAT_MIN_VERTICES)
-        self.use_flat = use_flat
 
-    # ------------------------------------------------------------------ #
     def match(self, jobspec: Jobspec) -> Optional[List[str]]:
         """Return the list of matched vertex paths, or None.
 
@@ -57,20 +49,24 @@ class Matcher:
             return got
 
     def _match(self, jobspec: Jobspec) -> Optional[List[str]]:
-        use_flat = self.use_flat
-        if use_flat and self._auto:
-            # auto dispatch also weighs the request: a small request on
-            # a big graph rides the pruned dict spine in microseconds,
-            # under the flat path's per-match setup cost
-            from .flatgraph import FLAT_REQ_RATIO
-            use_flat = (jobspec.graph_size() * FLAT_REQ_RATIO
-                        >= self.g.num_vertices)
-        if use_flat:
-            from .flatgraph import FlatMatcher
-            fm = FlatMatcher(self.g.flat())
-            got = fm.match(jobspec)
-            self.visited = fm.visited
-            return got
+        flat = flat_for(self.g, jobspec)
+        if flat is None:
+            return DictMatcher(self.g).match(jobspec)
+        return FlatMatcher(flat).match(jobspec)
+
+
+class DictMatcher:
+    """DFS matcher over the dict graph: a small graph's or a small
+    request's path, and the oracle the flat matcher is held to."""
+
+    def __init__(self, graph: ResourceGraph):
+        self.g = graph
+        # visit statistics, useful for verifying pruning behaviour
+        self.visited = 0
+
+    def match(self, jobspec: Jobspec) -> Optional[List[str]]:
+        """Return the list of matched vertex paths, or None (the
+        exclusive semantics of :meth:`Matcher.match`)."""
         self.visited = 0
         matched: List[str] = []
         claimed: Set[str] = set()

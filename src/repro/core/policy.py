@@ -46,7 +46,7 @@ from typing import Dict, List, Optional, Tuple, TYPE_CHECKING
 
 import numpy as np
 
-from .flatgraph import FLAT_MIN_VERTICES, flat_enabled
+from .flatgraph import flat_for
 from .metrics import NO_SPAN
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
@@ -139,13 +139,10 @@ class EasyBackfill(PriorityFCFS):
         fast = self.ledger and getattr(queue, "ledger", None) is not None
         if (self.max_candidates is None and fast and _sched_pure(queue)
                 and type(self).sort_key is SchedulingPolicy.sort_key):
-            g = queue.scheduler.graph
-            mir = getattr(queue, "_pmirror", None)
-            if mir is not None and (
-                    g._flat is not None
-                    or (flat_enabled()
-                        and g.num_vertices >= FLAT_MIN_VERTICES)):
-                return self._backfill_exact(queue, head, now, g.flat())
+            if getattr(queue, "_pmirror", None) is not None:
+                flat = flat_for(queue.scheduler.graph)
+                if flat is not None:
+                    return self._backfill_exact(queue, head, now, flat)
         shadow = shadow_time(queue, head, use_ledger=fast)
         structural = not _deficit(queue, head)
         started = 0
@@ -745,11 +742,10 @@ def _prefilter_ok(queue: "JobQueue", job: "Job") -> bool:
     the safe default: small graphs (batch scan not worth the mirror)
     and impure queues (escalation or preemption can beat the local
     mask) are never filtered."""
-    g = queue.scheduler.graph
-    if g._flat is None and (not flat_enabled()
-                            or g.num_vertices < FLAT_MIN_VERTICES):
-        return True
     if not _sched_pure(queue):
+        return True
+    g = queue.scheduler.graph
+    if flat_for(g) is None:
         return True
     gv = g.version
     if job._pf_version != gv:

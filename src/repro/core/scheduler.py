@@ -37,6 +37,7 @@ from ..analysis.lockwitness import named_rlock
 from .engine import Allocation, GrowEngine, GrowResult, MGTiming
 from .events import EventType
 from .external import ExternalProvider
+from .flatgraph import flat_for
 from .graph import ResourceGraph
 from .jobspec import Jobspec
 from .match import Matcher
@@ -106,10 +107,8 @@ class SchedulerInstance:
         # prewarm the flat-array mirror: schedulers are long-lived, so
         # the one-time build happens here (instance construction), not
         # inside the first match's timed region.  Small graphs stay on
-        # the dict DFS (see Matcher), so they skip mirror upkeep too.
-        from .flatgraph import FLAT_MIN_VERTICES, flat_enabled
-        if flat_enabled() and graph.num_vertices >= FLAT_MIN_VERTICES:
-            graph.flat()
+        # the dict DFS (see flat_for), so they skip mirror upkeep too.
+        flat_for(graph)
         self.methods = MethodRegistry()
         self.methods.register("match_grow", self._rpc_match_grow)
         self.methods.register("release", self._rpc_release)

@@ -1,3 +1,2 @@
-from .feasibility import batched_feasible_op
 from .ops import attention_op, ssd_scan_op
 from .ref import ref_attention, ref_ssd

@@ -1,10 +1,11 @@
-"""Batched feasibility scan — Pallas TPU kernel + XLA reference.
+"""Batched feasibility scan: a Pallas TPU kernel.
 
-The accelerator twin of ``core/flatgraph.batched_candidate_mask``: one
-pass over the ``agg[vertex, type]`` pruning table for a whole request
-matrix, producing the ``[N, V]`` root-feasibility mask the batched
-backfill prefilter consumes.  ``FlatGraph.feasible_roots_batch`` routes
-here when its ``use_jax`` dispatch picks the jax path.
+The device twin of ``core/flatgraph.batched_candidate_mask``, on the
+same operands: one pass over the ``agg[vertex, type]`` pruning table
+for a whole request matrix, producing the ``[N, V]`` root-feasibility
+mask the batched backfill prefilter consumes.
+``FlatGraph.feasible_roots_batch`` runs it when
+``flatgraph.on_device()`` holds (a TPU backend).
 
 Layout notes (TPU tiling wants the lane dim = 128):
 
@@ -16,10 +17,10 @@ Layout notes (TPU tiling wants the lane dim = 128):
 * 62-bit property masks are split into two nonneg int31 halves — TPUs
   have no practical int64 lane support (and jax defaults to x32).
 
-Grid is (N/BN, V/BV), both parallel; callers pad N, V, and T and slice
-the result.  ``auto`` runs the compiled kernel on TPU and the jitted XLA
-reference below everywhere else; interpret mode runs only when asked
-for (the CPU parity tests).
+Grid is (N/BN, V/BV), both parallel; :func:`pack_inputs` pads N, V and
+T and the caller slices the result.  :func:`run_packed` compiles the
+kernel for a TPU backend and interprets it on any other (the CPU tests
+that force the device half).
 """
 from __future__ import annotations
 
@@ -53,20 +54,6 @@ def _pad(a: np.ndarray, axis: int, mult: int, fill=0) -> np.ndarray:
     width = [(0, 0)] * a.ndim
     width[axis] = (0, ext)
     return np.pad(a, width, constant_values=fill)
-
-
-# ---------------------------------------------------------------------- #
-# XLA reference (the `auto` path off-TPU, and the parity oracle)
-# ---------------------------------------------------------------------- #
-@jax.jit
-def _ref_batched_feasible(vtype, vok, vsize, vmlo, vmhi, agg,
-                          tid, msize, rmlo, rmhi, need):
-    m = (vtype[None, :] == tid[:, None]) & (vok[None, :] != 0)
-    m &= vsize[None, :] >= msize[:, None]
-    m &= (vmlo[None, :] & rmlo[:, None]) == rmlo[:, None]
-    m &= (vmhi[None, :] & rmhi[:, None]) == rmhi[:, None]
-    m &= jnp.all(agg[None, :, :] >= need[:, None, :], axis=2)
-    return m.astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------- #
@@ -118,66 +105,33 @@ def _feasible_pallas(tid, msize, rmlo, rmhi, need,
 
 
 # ---------------------------------------------------------------------- #
-# dispatch (the kernels/ops.py idiom)
+# host half (pack) and device half (run)
 # ---------------------------------------------------------------------- #
 def pack_inputs(vtype: np.ndarray, vok: np.ndarray, vsize: np.ndarray,
                 vmask: np.ndarray, agg: np.ndarray,
                 tid: np.ndarray, msize: np.ndarray, rmask: np.ndarray,
-                need: np.ndarray,
-                use_pallas: str = "auto") -> Tuple[str, tuple]:
-    """The host half of :func:`batched_feasible_op`: the path it takes
-    (``'xla'``, ``'pallas'`` or ``'interpret'``) and the arguments of
-    that path's jitted program, cast to int32, property masks split,
-    and for the kernel padded to its blocks.  Their ``nbytes`` are what
-    the call copies to the device."""
+                need: np.ndarray) -> tuple:
+    """The kernel's arguments: cast to int32, property masks split, and
+    padded to its blocks.  ``vmask``/``rmask`` are the int64 property
+    bitmasks; ``agg`` is [V, T]; ``need`` is [N, T].  Their ``nbytes``
+    are what the call copies to the device."""
     vmlo, vmhi = _split_mask(vmask)
     rmlo, rmhi = _split_mask(rmask)
-    vtype = np.asarray(vtype, np.int32)
-    vok = np.asarray(vok, np.int32)
-    vsize = np.asarray(vsize, np.int32)
-    agg = np.asarray(agg, np.int32)
-    tid = np.asarray(tid, np.int32)
-    msize = np.asarray(msize, np.int32)
-    need = np.asarray(need, np.int32)
-    if use_pallas == "xla" or (use_pallas == "auto"
-                               and _backend() != "tpu"):
-        return "xla", (vtype, vok, vsize, vmlo, vmhi, agg,
-                       tid, msize, rmlo, rmhi, need)
+    i32 = lambda a: np.asarray(a, np.int32)                     # noqa: E731
     # pad request rows, vertex lanes, and the type sublane; padded
     # vertices carry vok=0 (never feasible) and padded types need=0
     # against agg=0 (vacuously satisfied)
-    rcol = lambda a: _pad(a.reshape(-1, 1), 0, _BN)             # noqa: E731
-    vrow = lambda a: _pad(a.reshape(1, -1), 1, _BV)             # noqa: E731
-    path = "interpret" if use_pallas == "interpret" else "pallas"
-    return path, (rcol(tid), rcol(msize), rcol(rmlo), rcol(rmhi),
-                  _pad(_pad(need, 0, _BN), 1, 8),
-                  vrow(vtype), vrow(vok), vrow(vsize), vrow(vmlo),
-                  vrow(vmhi), _pad(_pad(agg.T, 0, 8), 1, _BV))
+    rcol = lambda a: _pad(i32(a).reshape(-1, 1), 0, _BN)        # noqa: E731
+    vrow = lambda a: _pad(i32(a).reshape(1, -1), 1, _BV)        # noqa: E731
+    return (rcol(tid), rcol(msize), rcol(rmlo), rcol(rmhi),
+            _pad(_pad(i32(need), 0, _BN), 1, 8),
+            vrow(vtype), vrow(vok), vrow(vsize), vrow(vmlo), vrow(vmhi),
+            _pad(_pad(i32(agg).T, 0, 8), 1, _BV))
 
 
-def run_packed(path: str, args: tuple) -> np.ndarray:
-    """The device half: dispatch the packed call and pull the whole
-    int32 mask back to the host (padded on the kernel paths; rows are
-    requests, columns vertices)."""
-    if path == "xla":
-        return np.asarray(_ref_batched_feasible(*args))
+def run_packed(args: tuple) -> np.ndarray:
+    """Run the kernel on :func:`pack_inputs`' arguments and pull the
+    whole padded int32 mask back to the host (rows are requests,
+    columns vertices)."""
     return np.asarray(_feasible_pallas(*args,
-                                       interpret=path == "interpret"))
-
-
-def batched_feasible_op(vtype: np.ndarray, vok: np.ndarray,
-                        vsize: np.ndarray, vmask: np.ndarray,
-                        agg: np.ndarray,
-                        tid: np.ndarray, msize: np.ndarray,
-                        rmask: np.ndarray, need: np.ndarray,
-                        use_pallas: str = "auto") -> np.ndarray:
-    """[N, V] int32 mask: 1 where request ``i`` can root at vertex
-    ``v``.  ``vmask``/``rmask`` are the int64 property bitmasks;
-    ``agg`` is [V, T]; ``need`` is [N, T].
-
-    ``use_pallas``: ``'auto'`` (compiled kernel on TPU, XLA elsewhere),
-    ``'pallas'`` (compiled kernel; fails off TPU), ``'interpret'``,
-    ``'xla'``."""
-    out = run_packed(*pack_inputs(vtype, vok, vsize, vmask, agg, tid,
-                                  msize, rmask, need, use_pallas))
-    return out[:len(tid), :len(vtype)]
+                                       interpret=_backend() != "tpu"))

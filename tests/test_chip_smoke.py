@@ -1,9 +1,9 @@
 """``chip_smoke.py``'s phases at toy size on the CPU.
 
-The phases assert their own results; on the CPU the scheduling plane's
-``auto`` dispatch stays on numpy, so only the parity probes reach the
-jax path (the XLA reference, not the Pallas kernel).  The platform
-check lives in ``main()``, which must refuse to report success here.
+The phases assert their own results; on the CPU the scheduling plane
+stays on numpy, so only the parity probes reach the device halves (the
+Pallas kernel interpreted, the jitted sweep).  The platform check lives
+in ``main()``, which must refuse to report success here.
 """
 import dataclasses
 import importlib.util
@@ -28,7 +28,9 @@ def test_scheduling_phase_toy(smoke):
                                  arrivals=8, seed=3)
     assert res["jobs"] == 32 and res["n_vertices"] == 177
     assert res["probes"] >= 2 and res["sweep_calls"] >= res["probes"]
-    assert res["kernel_compiled"] == res["kernel_interpret"] == 0
+    # the CPU probes run the interpreted kernel, never the compiled one
+    assert res["kernel_compiled"] == 0
+    assert res["kernel_interpret"] >= res["probes"]
     assert res["platforms"] == {"cpu"}
     assert res["max_pending"] > 1
 

@@ -13,8 +13,9 @@ tests/test_graph.py).
 """
 import pytest
 
-from repro.core import (FlatMatcher, Jobspec, Matcher, add_subgraph,
-                        build_cluster, remove_subgraph, update_metadata)
+from repro.core import (DictMatcher, FlatMatcher, Jobspec, add_subgraph,
+                        build_cluster, flatgraph, remove_subgraph,
+                        update_metadata)
 from repro.core.graph import DOWN, UP
 
 try:
@@ -84,8 +85,8 @@ def test_flat_and_dict_matchers_identical():
         Jobspec.hpc(nodes=8, sockets=16, cores=64),   # unsatisfiable
     ]
     for js in specs:
-        flat = Matcher(g, use_flat=True).match(js)
-        oracle = Matcher(g, use_flat=False).match(js)
+        flat = FlatMatcher(g.flat()).match(js)
+        oracle = DictMatcher(g).match(js)
         assert flat == oracle
 
 
@@ -108,17 +109,34 @@ def test_flat_match_claims_are_exclusive():
     assert len(got) == len(set(got))
 
 
-def test_env_toggle_forces_dict_path(monkeypatch):
-    big = build_cluster(nodes=16)     # above FLAT_MIN_VERTICES
-    monkeypatch.setenv("CONVERGED_FLAT_MATCH", "0")
-    assert not Matcher(big).use_flat
-    monkeypatch.delenv("CONVERGED_FLAT_MATCH")
-    assert Matcher(big).use_flat
-    # small graphs default to the dict DFS (flat setup costs more than
-    # the whole match there); explicit use_flat=True still forces flat
+def test_env_toggle_forces_dict_path():
+    """The one flat-or-dict decision, ``flat_for``: the size cutoff,
+    the request ratio, and a mirror already kept."""
+    from repro.core.flatgraph import (FLAT_MIN_VERTICES, FLAT_REQ_RATIO,
+                                      flat_for)
+    big = build_cluster(nodes=16)
+    n = big.num_vertices
+    assert n >= FLAT_MIN_VERTICES
+    assert big._flat is None
+    one_core = Jobspec.hpc(nodes=1, sockets=1, cores=1)
+    whole = Jobspec.hpc(nodes=16, sockets=32, cores=128)
+    assert one_core.graph_size() * FLAT_REQ_RATIO < n
+    assert whole.graph_size() * FLAT_REQ_RATIO >= n
+    # a small request on a big graph: the dict DFS, no mirror built
+    assert flat_for(big, one_core) is None and big._flat is None
+    assert flat_for(big, whole) is big.flat()
+    assert flat_for(big) is big.flat()
+    # small graphs keep no mirror (flat setup costs more than the whole
+    # match there) ...
     small = build_cluster(nodes=2)
-    assert not Matcher(small).use_flat
-    assert Matcher(small, use_flat=True).use_flat
+    assert small.num_vertices < FLAT_MIN_VERTICES
+    assert flat_for(small) is None and small._flat is None
+    assert flat_for(small, whole) is None
+    # ... unless one is already kept
+    mirror = small.flat()
+    assert flat_for(small) is mirror
+    assert flat_for(small, whole) is mirror
+    assert flat_for(small, one_core) is None
 
 
 def test_tombstone_compaction_rebuilds_once():
@@ -176,16 +194,19 @@ def test_feasible_roots_batch_matches_sequential():
     assert not mask[-1].any()       # unknown type: empty row, no crash
 
 
-def test_feasible_roots_batch_jax_parity():
-    """use_jax='jax' (kernels/feasibility.py XLA path on CPU) must be
-    element-wise identical to the numpy path."""
+def test_feasible_roots_batch_jax_parity(monkeypatch):
+    """The device half (the kernels/feasibility.py kernel, interpreted
+    on the CPU) must be element-wise identical to the numpy half."""
     import numpy as np
     g = _churned_graph()
     flat = g.flat()
     reqs = [r for js in _batch_specs() for r in js.resources]
-    m_np = flat.feasible_roots_batch(reqs, use_jax="numpy")
-    m_jax = flat.feasible_roots_batch(reqs, use_jax="jax")
-    assert np.array_equal(m_np, m_jax)
+    m_np = flat.feasible_roots_batch(reqs)
+    assert flat.scan_h2d_bytes == 0
+    monkeypatch.setattr(flatgraph, "on_device", lambda: True)
+    m_dev = flat.feasible_roots_batch(reqs)
+    assert flat.scan_h2d_bytes > 0 and flat.scan_d2h_bytes > 0
+    assert np.array_equal(m_np, m_dev)
 
 
 def test_batched_path_agrees_with_dict_oracle():
@@ -197,8 +218,8 @@ def test_batched_path_agrees_with_dict_oracle():
     flat = g.flat()
     for js in _batch_specs():
         mask = flat.feasible_roots_batch(js.resources)
-        oracle = Matcher(g, use_flat=False).match(js)
-        got = Matcher(g, use_flat=True).match(js)
+        oracle = DictMatcher(g).match(js)
+        got = FlatMatcher(flat).match(js)
         assert got == oracle
         if not mask.any(axis=1).all():
             # some request has no feasible root anywhere: the oracle
@@ -207,10 +228,9 @@ def test_batched_path_agrees_with_dict_oracle():
 
 
 def test_aggregate_sweep_jax_cpu_parity():
-    """The jax aggregate_sweep path must agree element-wise with numpy
-    on CPU (satellite: CI runs this with jax[cpu])."""
+    """The jitted aggregate sweep must agree element-wise with the
+    numpy sweep on the CPU."""
     import numpy as np
-    from repro.core.flatgraph import aggregate_sweep
     g = _churned_graph()
     flat = g.flat()
     flat.sync()
@@ -218,10 +238,10 @@ def test_aggregate_sweep_jax_cpu_parity():
     own = np.zeros((n, T), np.int32)
     live = np.nonzero(flat.present[:n] & flat.free[:n])[0]
     own[live, flat.type_id[live]] = 1
-    a_np = aggregate_sweep(own, flat.parent[:n], flat._levels,
-                           use_jax="numpy")
-    a_jax = aggregate_sweep(own, flat.parent[:n], flat._levels,
-                            use_jax="jax")
+    a_np = flatgraph._aggregate_sweep_numpy(own, flat.parent[:n],
+                                            flat._levels)
+    a_jax = flatgraph._aggregate_sweep_jax(own, flat.parent[:n],
+                                           flat._levels)
     assert np.array_equal(a_np, np.asarray(a_jax))
     assert np.array_equal(a_np, flat.agg[:n, :T])
 
@@ -349,8 +369,8 @@ if HAS_HYPOTHESIS:
                 g.set_free([pool[idx]], f"j{idx}")
         js = Jobspec.hpc(nodes=nodes, sockets=sockets * nodes,
                          cores=cores * sockets * nodes)
-        flat = Matcher(g, use_flat=True).match(js)
-        oracle = Matcher(g, use_flat=False).match(js)
+        flat = FlatMatcher(g.flat()).match(js)
+        oracle = DictMatcher(g).match(js)
         assert flat == oracle
 else:
     def test_property_tests_skipped_without_hypothesis():

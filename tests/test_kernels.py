@@ -174,10 +174,13 @@ def _feasibility_numpy(vtype, vok, vsize, vmask, agg,
     (3, 40, 1024),      # deep window
 ])
 def test_batched_feasible_xla_vs_numpy(seed, n_req, n_vert):
-    from repro.kernels.feasibility import batched_feasible_op
+    """The scan's device half as the program runs it (``pack_inputs``,
+    then ``run_packed``: the kernel, interpreted off the TPU) against
+    the numpy oracle."""
+    from repro.kernels.feasibility import pack_inputs, run_packed
     case = _feasibility_case(seed, n_req, n_vert)
     want = _feasibility_numpy(*case)
-    got = batched_feasible_op(*case, use_pallas="xla")
+    got = run_packed(pack_inputs(*case))[:n_req, :n_vert]
     np.testing.assert_array_equal(got, want)
 
 
@@ -187,8 +190,15 @@ def test_batched_feasible_xla_vs_numpy(seed, n_req, n_vert):
     (4, 13, 97),
 ])
 def test_batched_feasible_pallas_interpret_vs_xla(seed, n_req, n_vert):
-    from repro.kernels.feasibility import batched_feasible_op
+    """The kernel in interpret mode against the numpy oracle, and the
+    program's host half (``flatgraph.batched_candidate_mask``) against
+    the same oracle."""
+    from repro.core.flatgraph import batched_candidate_mask
+    from repro.kernels.feasibility import _feasible_pallas, pack_inputs
     case = _feasibility_case(seed, n_req, n_vert)
-    ref = batched_feasible_op(*case, use_pallas="xla")
-    out = batched_feasible_op(*case, use_pallas="interpret")
-    np.testing.assert_array_equal(out, ref)
+    want = _feasibility_numpy(*case)
+    out = np.asarray(_feasible_pallas(*pack_inputs(*case), interpret=True))
+    np.testing.assert_array_equal(out[:n_req, :n_vert], want)
+    vtype, vok = case[:2]
+    host = batched_candidate_mask(vtype, vok != 0, *case[2:])
+    np.testing.assert_array_equal(host.astype(np.int32), want)

@@ -48,9 +48,9 @@ def _drive(inst):
 
 @pytest.fixture
 def device_path(monkeypatch):
-    """Take the accelerator dispatch on the CPU (XLA in place of the
-    kernel), so the scan's device half runs."""
-    monkeypatch.setattr(flatgraph, "_on_accelerator", lambda: True)
+    """Take the device path on the CPU (the kernel interpreted, the
+    sweep jitted), so the scan's device half runs."""
+    monkeypatch.setattr(flatgraph, "on_device", lambda: True)
 
 
 def test_detached_records_nothing(device_path):
